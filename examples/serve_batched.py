@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import ServeEngine
 
@@ -106,6 +107,7 @@ def main():
                   f"pp={b.pipeline_parallel} dp={b.data_parallel} -> "
                   f"{report.best_sim.throughput_tokens:,.0f} tok/s simulated")
 
+    enable_compile_cache()
     arch = get_reduced(args.arch)
     cfg = lm.ModelCfg(dtype=jnp.float32, attn_impl="xla", ssm_impl="xla")
     params = lm.init_params(arch, jax.random.PRNGKey(0))

@@ -42,13 +42,8 @@ def main():
     arch = SIZES[args.size]
     print(f"training {arch.name}: {arch.total_params()/1e6:.1f}M params")
 
-    # reuse the production driver with an explicit arch (register in place so
-    # every module-level reference sees it)
-    import repro.configs as configs
-
-    configs.PAPER_MODELS[arch.name] = arch
+    # reuse the production driver with an explicit arch
     train_mod.main([
-        "--arch", arch.name,
         "--steps", str(args.steps),
         "--batch", str(args.batch),
         "--seq", str(args.seq),
@@ -56,7 +51,7 @@ def main():
         "--checkpoint-dir", args.checkpoint_dir,
         "--checkpoint-every", "50",
         "--log-every", "10",
-    ])
+    ], arch=arch)
 
 
 if __name__ == "__main__":

@@ -19,23 +19,38 @@ import numpy as np
 
 class MarkovCorpus:
     """First-order Markov chain over ``vocab`` states with temperature
-    controlling how predictable transitions are (lower => lower entropy)."""
+    controlling how predictable transitions are (lower => lower entropy).
+
+    Each state moves to one of ``FANOUT`` successors — the state plus one of
+    ``FANOUT`` distinct random offsets — with its own random logits. The
+    chain is held as (vocab, FANOUT) tables: a dense vocab x vocab matrix
+    would take 8 GB per copy at a 32k vocabulary."""
+
+    FANOUT = 64
 
     def __init__(self, vocab: int, seed: int = 0, temperature: float = 0.3):
         rng = np.random.default_rng(seed)
-        logits = rng.normal(size=(vocab, vocab)) / max(temperature, 1e-3)
-        z = logits - logits.max(axis=1, keepdims=True)
-        p = np.exp(z)
-        self.P = p / p.sum(axis=1, keepdims=True)  # (V, V)
+        k = min(self.FANOUT, vocab)
+        offsets = rng.choice(vocab, size=k, replace=False)
+        self.succ = (np.arange(vocab)[:, None] + offsets[None, :]) % vocab  # (V, k)
+        logits = rng.normal(size=(vocab, k)) / max(temperature, 1e-3)
+        p = np.exp(logits - logits.max(axis=1, keepdims=True))
+        self.P = p / p.sum(axis=1, keepdims=True)  # (V, k), over succ
         self.vocab = vocab
         self._cum = np.cumsum(self.P, axis=1)
+        self._cum[:, -1] = 1.0  # rounding must not leave u above every bucket
 
     def entropy_rate(self) -> float:
-        """Bits... nats per token of the stationary chain (loss floor)."""
+        """Nats per token of the stationary chain (loss floor)."""
         # stationary distribution via power iteration
         pi = np.full(self.vocab, 1.0 / self.vocab)
         for _ in range(200):
-            pi = pi @ self.P
+            nxt = np.bincount(self.succ.ravel(), weights=(pi[:, None] * self.P).ravel(),
+                              minlength=self.vocab)
+            done = np.abs(nxt - pi).sum() < 1e-12
+            pi = nxt
+            if done:
+                break
         H = -(self.P * np.log(np.maximum(self.P, 1e-12))).sum(axis=1)
         return float((pi * H).sum())
 
@@ -45,7 +60,8 @@ class MarkovCorpus:
         out[:, 0] = state
         for t in range(1, seq):
             u = rng.random(batch)
-            state = (self._cum[state] > u[:, None]).argmax(axis=1)
+            pick = (self._cum[state] > u[:, None]).argmax(axis=1)
+            state = self.succ[state, pick]
             out[:, t] = state
         return out
 

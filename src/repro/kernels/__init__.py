@@ -1,3 +1,18 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (flash attention, RMSNorm, Mamba-2 SSD), their pure-jnp
+oracles (``ref``) and the differentiable wrappers the model calls (``ops``)."""
+from __future__ import annotations
+
+import jax
+
+
+def check_interpret(interpret: bool) -> None:
+    """Refuse the Pallas interpreter off the CPU backend.
+
+    A kernel asked to interpret on an accelerator would run a Python-level
+    emulation there and report no error, so on a TPU every kernel compiles
+    natively; tests on the CPU ask for ``interpret=True`` explicitly."""
+    if interpret and jax.default_backend() != "cpu":
+        raise ValueError(
+            f"interpret=True is for the CPU backend only; the "
+            f"{jax.default_backend()!r} backend compiles Pallas kernels natively"
+        )

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import check_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
@@ -37,6 +39,8 @@ def _flash_fwd_kernel(
     kv_len: int,
     q_offset: int,
 ):
+    # every value is kept 2-D: Mosaic has no layout for 1-D vectors, so the
+    # running max / sum are (bq, 1) columns and masks come from 2-D iotas
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -52,34 +56,35 @@ def _flash_fwd_kernel(
     q_start = q_offset + iq * block_q
 
     def body():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        q = q_ref[0, 0]  # (bq, D)
+        k = k_ref[0, 0]  # (bk, D)
+        v = v_ref[0, 0]  # (bk, D)
         # zero the KV rows beyond the true length: out-of-bounds block padding
         # is undefined (NaN in interpret mode) and 0 * NaN would poison p @ v
-        valid_k = ik * block_k + jax.lax.iota(jnp.int32, block_k) < kv_len  # (bk,)
-        k = jnp.where(valid_k[:, None], k, 0.0)
-        v = jnp.where(valid_k[:, None], v, 0.0)
+        k_row = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        k = jnp.where(k_row < kv_len, k, 0)
+        v = jnp.where(k_row < kv_len, v, 0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale  # (bq, bk)
 
+        k_pos = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1
+        )
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         # mask padding beyond the true kv length
-        s = jnp.where(valid_k[None, :], s, _NEG_INF)
+        s = jnp.where(k_pos < kv_len, s, _NEG_INF)
 
-        m_prev = m_scr[...]  # (bq,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_scr[...]  # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p = jnp.exp(s - m_cur)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         m_scr[...] = m_cur
 
@@ -95,7 +100,7 @@ def _flash_fwd_kernel(
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(l_safe)
 
 
@@ -114,10 +119,15 @@ def flash_attention_fwd(
     sm_scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
     q_offset: Optional[int] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (out (B,Hq,S,D), lse (B,Hq,S))."""
+    """Returns (out (B,Hq,S,D), lse (B,Hq,S)).
+
+    ``interpret=True`` runs the Pallas interpreter, which only the CPU
+    backend may do (tests); everywhere else the kernel compiles natively.
+    """
+    check_interpret(interpret)
     B, Hq, S, D = q.shape
     Hkv, T = k.shape[1], k.shape[2]
     assert Hq % Hkv == 0, (Hq, Hkv)
@@ -152,17 +162,20 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, S, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        ),
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse[..., 0]

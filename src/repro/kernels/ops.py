@@ -1,10 +1,11 @@
 """Public, differentiable wrappers over the Pallas kernels.
 
 Each op takes ``impl``:
-  * "pallas"  — interpret-mode Pallas forward (CPU validation; compiles
-                natively on real TPUs) with a recompute-based backward —
-                the flash-attention backward IS recomputation, so grads are
-                memory-frugal by construction.
+  * "pallas"  — the Pallas forward, compiled natively on a TPU, with a
+                recompute-based backward — the flash-attention backward IS
+                recomputation, so grads are memory-frugal by construction.
+                ``interpret=True`` runs the Pallas interpreter instead, which
+                only the CPU backend may do (tests).
   * "xla"     — the pure-jnp reference, used inside the 512-device dry-run
                 lowering where interpret-mode callbacks cannot be
                 SPMD-partitioned (DESIGN.md §5).
@@ -27,18 +28,21 @@ from repro.kernels.ssd import ssd_scan_fwd
 # flash attention
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention_pallas(q, k, v, causal: bool, sm_scale: Optional[float]):
-    out, _ = flash_attention_fwd(q, k, v, causal=causal, sm_scale=sm_scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention_pallas(q, k, v, causal: bool, sm_scale: Optional[float],
+                            interpret: bool):
+    out, _ = flash_attention_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
+                                 interpret=interpret)
     return out
 
 
-def _fa_fwd(q, k, v, causal, sm_scale):
-    out, _ = flash_attention_fwd(q, k, v, causal=causal, sm_scale=sm_scale)
+def _fa_fwd(q, k, v, causal, sm_scale, interpret):
+    out, _ = flash_attention_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
+                                 interpret=interpret)
     return out, (q, k, v)
 
 
-def _fa_bwd(causal, sm_scale, res, g):
+def _fa_bwd(causal, sm_scale, interpret, res, g):
     q, k, v = res
     # flash backward == blockwise recompute; the reference VJP is the oracle
     # formulation of exactly that recomputation.
@@ -60,15 +64,16 @@ def flash_attention(
     causal: bool = True,
     sm_scale: Optional[float] = None,
     impl: str = "pallas",
+    interpret: bool = False,
 ) -> jax.Array:
     """GQA flash attention. q: (B,Hq,S,D), k/v: (B,Hkv,T,D).
 
-    impl: "pallas" (TPU kernel, interpret-mode on CPU), "xla" (scan-based
+    impl: "pallas" (TPU kernel), "xla" (scan-based
     online softmax — memory-sane for 32k+ and SPMD-partitionable), "naive"
     (the O(S*T)-memory oracle, tests only).
     """
     if impl == "pallas":
-        return _flash_attention_pallas(q, k, v, causal, sm_scale)
+        return _flash_attention_pallas(q, k, v, causal, sm_scale, interpret)
     if impl == "xla":
         from repro.kernels.xla_flash import flash_xla_train
 
@@ -80,29 +85,30 @@ def flash_attention(
 # rmsnorm
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rmsnorm_pallas(x, w, eps: float):
-    return rmsnorm_fwd(x, w, eps=eps)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rmsnorm_pallas(x, w, eps: float, interpret: bool):
+    return rmsnorm_fwd(x, w, eps=eps, interpret=interpret)
 
 
-def _rn_fwd(x, w, eps):
-    return rmsnorm_fwd(x, w, eps=eps), (x, w)
+def _rn_fwd(x, w, eps, interpret):
+    return rmsnorm_fwd(x, w, eps=eps, interpret=interpret), (x, w)
 
 
-def _rn_bwd(eps, res, g):
+def _rn_bwd(eps, interpret, res, g):
     x, w = res
     _, vjp = jax.vjp(lambda x_, w_: ref.rmsnorm(x_, w_, eps=eps), x, w)
     return vjp(g)
 
 
-_rnsig = _rmsnorm_pallas.defvjp(_rn_fwd, _rn_bwd)
+_rmsnorm_pallas.defvjp(_rn_fwd, _rn_bwd)
 
 
 def fused_rmsnorm(
-    x: jax.Array, weight: jax.Array, *, eps: float = 1e-6, impl: str = "pallas"
+    x: jax.Array, weight: jax.Array, *, eps: float = 1e-6, impl: str = "pallas",
+    interpret: bool = False,
 ) -> jax.Array:
     if impl == "pallas":
-        return _rmsnorm_pallas(x, weight, eps)
+        return _rmsnorm_pallas(x, weight, eps, interpret)
     return ref.rmsnorm(x, weight, eps=eps)
 
 
@@ -110,18 +116,18 @@ def fused_rmsnorm(
 # SSD scan
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
-def _ssd_pallas(x, dt, A, Bm, C, D):
-    y, _ = ssd_scan_fwd(x, dt, A, Bm, C, D)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_pallas(x, dt, A, Bm, C, D, interpret: bool):
+    y, _ = ssd_scan_fwd(x, dt, A, Bm, C, D, interpret=interpret)
     return y
 
 
-def _ssd_fwd(x, dt, A, Bm, C, D):
-    y, _ = ssd_scan_fwd(x, dt, A, Bm, C, D)
+def _ssd_fwd(x, dt, A, Bm, C, D, interpret):
+    y, _ = ssd_scan_fwd(x, dt, A, Bm, C, D, interpret=interpret)
     return y, (x, dt, A, Bm, C, D)
 
 
-def _ssd_bwd(res, g):
+def _ssd_bwd(interpret, res, g):
     x, dt, A, Bm, C, D = res
     _, vjp = jax.vjp(lambda *a: ref.ssd_scan(*a), x, dt, A, Bm, C, D)
     return vjp(g)
@@ -139,12 +145,13 @@ def ssd(
     D: Optional[jax.Array] = None,
     *,
     impl: str = "pallas",
+    interpret: bool = False,
 ) -> jax.Array:
     """Mamba-2 SSD mixer. Training form (no state I/O)."""
     if D is None:
         D = jnp.zeros((x.shape[2],), jnp.float32)
     if impl == "pallas":
-        return _ssd_pallas(x, dt, A, Bm, C, D)
+        return _ssd_pallas(x, dt, A, Bm, C, D, interpret)
     return ref.ssd_scan(x, dt, A, Bm, C, D)
 
 

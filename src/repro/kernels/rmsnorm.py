@@ -11,6 +11,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import check_interpret
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -19,8 +22,9 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, n_rows: int, block_rows:
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)  # (br, D)
     # zero padding rows so their garbage cannot produce inf/nan warnings
-    valid = i * block_rows + jax.lax.iota(jnp.int32, block_rows) < n_rows
-    x = jnp.where(valid[:, None], x, 0.0)
+    # (a 2-D iota: Mosaic cannot reshape a 1-D mask into a column)
+    row = i * block_rows + jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1), 0)
+    x = jnp.where(row < n_rows, x, 0.0)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + eps) * w_ref[...].astype(jnp.float32)
     o_ref[...] = y.astype(o_ref.dtype)
@@ -33,8 +37,10 @@ def rmsnorm_fwd(
     *,
     eps: float = 1e-6,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
+    """``interpret=True`` runs the Pallas interpreter (CPU backend only)."""
+    check_interpret(interpret)
     orig_shape = x.shape
     D = orig_shape[-1]
     x2 = x.reshape(-1, D)
@@ -46,10 +52,11 @@ def rmsnorm_fwd(
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((D,), lambda i: (0,)),
+            pl.BlockSpec((1, D), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, D), x.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x2, weight)
+    )(x2, weight.reshape(1, D))
     return out.reshape(orig_shape)

@@ -179,7 +179,7 @@ def lower_cell(
             )
             args = (p_struct, specs["caches"], specs["tokens"], specs["position"])
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.perf_counter() - t0
         t0 = time.perf_counter()
@@ -208,11 +208,7 @@ def lower_cell(
     # cost_analysis counts scan bodies once (see hlo_account docstring), so
     # the roofline terms come from the call-graph accountant; the raw numbers
     # are kept for reference.
-    # jax < 0.5 returns a list with one dict per computation; newer jax
-    # returns the dict directly
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     report["cost_analysis_raw"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

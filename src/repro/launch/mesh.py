@@ -6,26 +6,25 @@ XLA flag before any jax initialization, and tests/benches see 1 device.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
-
-
-def axis_types_kwargs(num_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh``, or ``{}`` on jax versions
-    (< 0.5) that predate ``jax.sharding.AxisType`` and always build classic
-    (auto) meshes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = one v5e pod-slice; 2x16x16 = two pods over DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh with classic (auto) axis semantics."""
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+def make_mesh(
+    shape: tuple[int, ...],
+    axes: tuple[str, ...],
+    devices: Optional[Sequence[jax.Device]] = None,
+) -> jax.sharding.Mesh:
+    """Mesh with classic (auto) axis semantics over ``devices`` (default:
+    every device of the process)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

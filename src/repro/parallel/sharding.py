@@ -49,27 +49,19 @@ class ShardingPlan:
         return int(np.prod([self.mesh.shape[a] for a in self.batch_axes]) or 1)
 
 
-def ambient_mesh() -> Optional[Mesh]:
-    """The mesh installed by ``with mesh:`` at trace time (None outside)."""
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
 def constrain_batch_sharding(x, batch_axes: tuple[str, ...] = ("pod", "data")):
-    """Pin dim 0 of ``x`` to the ambient mesh's batch axes.
+    """Pin dim 0 of ``x`` to the batch axes of the mesh set by
+    ``jax.set_mesh`` (a no-op outside one).
 
     Layer-scan carries must not be left to GSPMD propagation: with weights
     sharded over both "model" and "data" (FSDP x TP) the partitioner picks a
     batch-dim resharding for the carry that forces involuntary
-    rematerializations and — on the CPU backend of jax 0.4.x — miscompiles
-    the scan outright (dp-parity divergence of O(0.1) in the loss). An
-    explicit constraint keeps the carry data-sharded, which is both the
-    correct layout and the workaround.
+    rematerializations and has miscompiled the scan outright (dp-parity
+    divergence of O(0.1) in the loss). An explicit constraint keeps the
+    carry data-sharded, which is the correct layout.
     """
-    mesh = ambient_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     axes = tuple(a for a in batch_axes if a in mesh.axis_names)
     if not axes:
@@ -77,9 +69,7 @@ def constrain_batch_sharding(x, batch_axes: tuple[str, ...] = ("pod", "data")):
     size = int(np.prod([mesh.shape[a] for a in axes]))
     if size <= 1 or x.shape[0] % size != 0:
         return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(axes, *([None] * (x.ndim - 1))))
-    )
+    return jax.lax.with_sharding_constraint(x, P(axes, *([None] * (x.ndim - 1))))
 
 
 def make_plan(

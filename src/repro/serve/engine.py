@@ -31,6 +31,10 @@ class GenerateResult:
     # Trace emitters must drop these — a compile-polluted step skews drift
     # scoring toward spurious refits
     warmup_steps: int = 0
+    # wall seconds from the call until the first generated token is on the
+    # host (cache setup + prefill + first sample; includes compilation on
+    # the first generate at a given prompt shape)
+    first_token_s: float = 0.0
 
 
 class ServeEngine:
@@ -59,6 +63,7 @@ class ServeEngine:
         enc_features=None,
         frontend=None,
     ) -> GenerateResult:
+        t_start = time.perf_counter()
         B, S = prompts.shape
         frontend_len = frontend.shape[1] if frontend is not None else 0
         total = S + frontend_len + max_new_tokens
@@ -84,6 +89,7 @@ class ServeEngine:
         pos = S + frontend_len
         warmup = 0 if B in self._warm_batches else min(1, max_new_tokens)
         step_times = []
+        first_token_s = 0.0
         for i in range(max_new_tokens):
             t0 = time.perf_counter()
             if temperature > 0:
@@ -96,6 +102,8 @@ class ServeEngine:
             # decode dispatched last iteration — so the measured interval is
             # a true per-token step time, not just dispatch latency
             out.append(np.asarray(nxt))
+            if i == 0:
+                first_token_s = time.perf_counter() - t_start
             logits, caches = self._decode(
                 self.params, caches=caches, tokens=nxt, position=pos + i
             )
@@ -106,4 +114,5 @@ class ServeEngine:
         return GenerateResult(
             tokens=np.concatenate(out, axis=1), prompt_len=S,
             step_times=tuple(step_times), warmup_steps=warmup,
+            first_token_s=first_token_s,
         )

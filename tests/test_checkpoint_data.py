@@ -122,3 +122,30 @@ def test_pipeline_shards_disjoint_deterministic():
 def test_markov_entropy_below_uniform():
     c = MarkovCorpus(64, seed=0, temperature=0.3)
     assert c.entropy_rate() < np.log(64) * 0.85
+
+
+def test_markov_chain_matches_dense_oracle():
+    """The (vocab, FANOUT) tables describe the same chain as the dense
+    transition matrix they stand for: same entropy rate, and every sampled
+    transition has positive probability there."""
+    V = 4 * MarkovCorpus.FANOUT
+    c = MarkovCorpus(V, seed=0)
+    assert c.succ.shape == (V, MarkovCorpus.FANOUT)
+    dense = np.zeros((V, V))
+    np.add.at(dense, (np.arange(V)[:, None], c.succ), c.P)
+    np.testing.assert_allclose(dense.sum(axis=1), 1.0)
+    pi = np.full(V, 1.0 / V)
+    for _ in range(2000):
+        pi = pi @ dense
+    h = -(dense * np.log(np.where(dense > 0, dense, 1.0))).sum(axis=1)
+    np.testing.assert_allclose(c.entropy_rate(), float(pi @ h), rtol=1e-6)
+    toks = c.sample(np.random.default_rng(0), 16, 32)
+    assert (dense[toks[:, :-1], toks[:, 1:]] > 0).all()
+
+
+def test_markov_corpus_at_a_32k_vocab_stays_small():
+    c = MarkovCorpus(32000, seed=0)
+    assert c.P.nbytes + c.succ.nbytes + c._cum.nbytes < 64 * 2**20
+    toks = c.sample(np.random.default_rng(0), 4, 64)
+    assert toks.min() >= 0 and toks.max() < 32000
+    assert 0 < c.entropy_rate() < np.log(64)

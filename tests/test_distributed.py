@@ -57,7 +57,7 @@ def test_dp_parity_vs_single_device():
                                      is_leaf=lambda x: isinstance(x, P))
         params_d = jax.tree_util.tree_map(jax.device_put, params, psh)
         batch_d = jax.tree_util.tree_map(jax.device_put, batch, bsh)
-        with mesh:
+        with jax.set_mesh(mesh):
             p2, _, m2 = jax.jit(step)(params_d, adamw_init(params_d), batch_d)
         err = jax.tree_util.tree_map(
             lambda a, b: float(jnp.abs(a - b).max()), p1, jax.device_get(p2))
@@ -151,7 +151,7 @@ def test_elastic_restart_across_mesh_shapes():
 
         # interrupted: 2 steps on (8,1), save, restore onto (2,4), 2 more
         mesh_a = make_mesh((8, 1), ("data", "model"))
-        with mesh_a:
+        with jax.set_mesh(mesh_a):
             p, o = params, opt
             for b in batches[:2]:
                 p, o, m = jax.jit(step_fn)(p, o, b)
@@ -167,7 +167,7 @@ def test_elastic_restart_across_mesh_shapes():
         state, meta = mgr.restore({"params": params, "opt": opt},
                                   shardings={"params": psh})
         p, o = state["params"], state["opt"]
-        with mesh_b:
+        with jax.set_mesh(mesh_b):
             for b in batches[2:]:
                 p, o, m = jax.jit(step_fn)(p, o, b)
         print("REF", ref_loss)

@@ -1,4 +1,6 @@
-"""Per-kernel allclose sweeps against the ref.py oracles (interpret mode)."""
+"""Per-kernel allclose sweeps against the ref.py oracles, in interpret mode
+(the CPU backend cannot compile Pallas; tests/test_tpu_compile.py compiles the
+same kernels for a described v5e)."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -35,7 +37,7 @@ _TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_pallas_flash_vs_oracle(B, Hq, Hkv, S, T, D, causal, dtype):
     q, k, v = _qkv(B, Hq, Hkv, S, T, D, dtype)
-    out, _ = flash_attention_fwd(q, k, v, causal=causal)
+    out, _ = flash_attention_fwd(q, k, v, causal=causal, interpret=True)
     expected = ref.attention(q, k, v, causal=causal)
     err = jnp.abs(out.astype(jnp.float32) - expected.astype(jnp.float32)).max()
     assert float(err) < _TOL[dtype], float(err)
@@ -45,13 +47,14 @@ def test_pallas_flash_block_shape_sweep():
     q, k, v = _qkv(1, 2, 2, 256, 256, 64)
     expected = ref.attention(q, k, v, causal=True)
     for bq, bk in [(64, 64), (128, 256), (256, 128)]:
-        out, _ = flash_attention_fwd(q, k, v, causal=True, block_q=bq, block_k=bk)
+        out, _ = flash_attention_fwd(q, k, v, causal=True, block_q=bq, block_k=bk,
+                                     interpret=True)
         assert float(jnp.abs(out - expected).max()) < 2e-5, (bq, bk)
 
 
 def test_flash_ops_grad_matches_oracle():
     q, k, v = _qkv(1, 4, 2, 128, 128, 64)
-    gp = jax.grad(lambda q: ops.flash_attention(q, k, v, impl="pallas").sum())(q)
+    gp = jax.grad(lambda q: ops.flash_attention(q, k, v, impl="pallas", interpret=True).sum())(q)
     gx = jax.grad(lambda q: ops.flash_attention(q, k, v, impl="naive").sum())(q)
     assert float(jnp.abs(gp - gx).max()) < 1e-5
 
@@ -108,7 +111,7 @@ def test_banded_flash_vs_banded_oracle():
 def test_rmsnorm_vs_oracle(shape, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (shape[-1],), dtype)
-    out = rmsnorm_fwd(x, w, block_rows=64)
+    out = rmsnorm_fwd(x, w, block_rows=64, interpret=True)
     expected = ref.rmsnorm(x, w)
     err = jnp.abs(out.astype(jnp.float32) - expected.astype(jnp.float32)).max()
     assert float(err) < _TOL[dtype]
@@ -117,7 +120,7 @@ def test_rmsnorm_vs_oracle(shape, dtype):
 def test_rmsnorm_grad():
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 32, 96))
     w = jnp.ones((96,))
-    g1 = jax.grad(lambda x: ops.fused_rmsnorm(x, w, impl="pallas").sum())(x)
+    g1 = jax.grad(lambda x: ops.fused_rmsnorm(x, w, impl="pallas", interpret=True).sum())(x)
     g2 = jax.grad(lambda x: ref.rmsnorm(x, w).sum())(x)
     assert float(jnp.abs(g1 - g2).max()) < 1e-6
 
@@ -144,10 +147,23 @@ def _ssd_inputs(B, S, H, P, N, dtype=jnp.float32, seed=0):
 ])
 def test_ssd_kernel_vs_oracle(B, S, H, P, N, chunk):
     x, dt, A, Bm, C, D = _ssd_inputs(B, S, H, P, N)
-    y, state = ssd_scan_fwd(x, dt, A, Bm, C, D, chunk=chunk)
+    y, state = ssd_scan_fwd(x, dt, A, Bm, C, D, chunk=chunk, interpret=True)
     ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, return_state=True)
     assert float(jnp.abs(y - ye).max()) < 2e-3
     assert float(jnp.abs(state - se).max()) < 2e-3
+
+
+def test_ssd_kernel_keeps_precision_under_strong_decay():
+    """A chunk whose cumulative log-decay reaches hundreds: decays over a
+    span must not be the difference of two such sums, which keeps only
+    eps * |sum| of absolute precision."""
+    x, dt, _, Bm, C, D = _ssd_inputs(1, 256, 2, 16, 8)
+    dt = dt + 2.0
+    A = jnp.array([-4.0, -0.5])
+    y, state = ssd_scan_fwd(x, dt, A, Bm, C, D, interpret=True)
+    ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, return_state=True)
+    assert float(jnp.abs(y - ye).max() / jnp.abs(ye).max()) < 1e-6
+    assert float(jnp.abs(state - se).max() / jnp.abs(se).max()) < 1e-6
 
 
 def test_ssd_streaming_equals_full():
@@ -164,6 +180,25 @@ def test_ssd_streaming_equals_full():
 
 def test_ssd_grad_parity():
     x, dt, A, Bm, C, D = _ssd_inputs(1, 128, 2, 16, 8)
-    g1 = jax.grad(lambda x: ops.ssd(x, dt, A, Bm, C, impl="pallas").sum())(x)
+    g1 = jax.grad(lambda x: ops.ssd(x, dt, A, Bm, C, impl="pallas", interpret=True).sum())(x)
     g2 = jax.grad(lambda x: ops.ssd(x, dt, A, Bm, C, impl="xla").sum())(x)
     assert float(jnp.abs(g1 - g2).max()) < 1e-5
+
+
+@pytest.mark.parametrize("call", ["flash_attention", "rmsnorm", "ssd"])
+def test_interpret_refused_off_the_cpu(monkeypatch, call):
+    """On an accelerator a kernel compiles natively: asking it to interpret
+    there is an error, never a silent emulation."""
+    import repro.kernels as kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    # the check runs when the jitted kernel is traced: drop traces that
+    # earlier CPU tests made at these shapes
+    jax.clear_caches()
+    with pytest.raises(ValueError, match="CPU backend only"):
+        if call == "flash_attention":
+            flash_attention_fwd(*_qkv(1, 2, 2, 128, 128, 64), interpret=True)
+        elif call == "rmsnorm":
+            rmsnorm_fwd(jnp.ones((8, 128)), jnp.ones((128,)), interpret=True)
+        else:
+            ssd_scan_fwd(*_ssd_inputs(1, 128, 2, 16, 8), interpret=True)

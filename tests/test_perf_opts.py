@@ -84,7 +84,7 @@ def test_act_shard_constraints_are_noop_numerically(setup):
     cfg = dataclasses.replace(
         CFG, act_shard={"batch": ("data",), "model": "model"}
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         out = lm.forward_logits(params, arch, cfg, {"tokens": toks})
     assert float(jnp.abs(out - full).max()) == 0.0
 

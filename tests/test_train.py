@@ -114,3 +114,17 @@ def test_bf16_grad_accumulation_close_to_fp32():
         lambda a, b: float(jnp.abs(a - b).max() / (jnp.abs(a).max() + 1e-9)), p32, p16
     )
     assert max(jax.tree_util.tree_leaves(rel)) < 0.05
+
+
+def test_auto_strategy_fails_when_eta_model_fails(monkeypatch):
+    """No fallback model: a search priced by another model would pick another
+    plan, so a failure to load or train the eta model fails the run."""
+    from repro.launch import train as train_mod
+
+    def broken():
+        raise RuntimeError("eta model unavailable")
+
+    monkeypatch.setattr(train_mod, "load_or_train", broken)
+    with pytest.raises(RuntimeError, match="eta model unavailable"):
+        train_mod.main(["--arch", "yi-6b", "--reduced", "--auto-strategy",
+                        "--steps", "1", "--batch", "4", "--seq", "32"])
