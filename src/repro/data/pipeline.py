@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class MarkovCorpus:
@@ -83,11 +84,13 @@ class SyntheticPipeline:
         return self.global_batch // self.num_shards
 
     def next_batch(self) -> dict:
-        """Tokens for this host's shard at the current step (advances cursor)."""
-        rng = np.random.default_rng(
-            (self.step * 1_000_003 + self.shard_index) & 0x7FFFFFFF
-        )
-        tokens = self.corpus.sample(rng, self.shard_batch, self.seq_len)
+        """Tokens for this host's shard at the current step (advances cursor);
+        a ``data.next_batch`` span in a profile."""
+        with TraceAnnotation("data.next_batch"):
+            rng = np.random.default_rng(
+                (self.step * 1_000_003 + self.shard_index) & 0x7FFFFFFF
+            )
+            tokens = self.corpus.sample(rng, self.shard_batch, self.seq_len)
         self.step += 1
         return {"tokens": tokens}
 

@@ -154,6 +154,7 @@ def flash_attention_fwd(
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),

@@ -49,6 +49,7 @@ def rmsnorm_fwd(
     grid = (pl.cdiv(n, br),)
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps, n_rows=n, block_rows=br),
+        name="rmsnorm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),

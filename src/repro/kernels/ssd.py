@@ -150,6 +150,7 @@ def ssd_scan_fwd(
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     y, state = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=L, seq_len=S),
+        name="ssd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, L, P), lambda b, h, ic: (b, h, ic, 0)),

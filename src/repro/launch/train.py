@@ -55,15 +55,19 @@ def pick_strategy(arch, num_devices: int, global_batch: int, seq: int):
     a search priced by another model would pick another plan.
 
     The search is serial (``workers=1``): callers hold the chip, and a
-    worker process forked from them could not use it."""
-    eta, _ = load_or_train()
-    service = SearchService(Astra(eta))
-    return service.search(SearchSpec(
-        arch=arch,
-        pool=FixedPool("tpu-v5e", max(num_devices, 1)),
-        workload=Workload(global_batch, seq),
-        limits=Limits(workers=1),
-    ))
+    worker process forked from them could not use it. In a profile it is the
+    ``search`` span, the eta model's loading or training its
+    ``search.eta_model`` child."""
+    with jax.profiler.TraceAnnotation("search"):
+        with jax.profiler.TraceAnnotation("search.eta_model"):
+            eta, _ = load_or_train()
+        service = SearchService(Astra(eta))
+        return service.search(SearchSpec(
+            arch=arch,
+            pool=FixedPool("tpu-v5e", max(num_devices, 1)),
+            workload=Workload(global_batch, seq),
+            limits=Limits(workers=1),
+        ))
 
 
 def _memory_in_use(devices) -> list:
@@ -194,20 +198,22 @@ def main(argv=None, *, arch: Optional[ModelArch] = None,
         compile_s = time.perf_counter() - t_compile
         print(f"[compile] train step {compile_s:.2f}s")
         for step in range(start_step, args.steps):
-            if step > start_step:
-                batch = next_batch(step)
-            t_step = time.perf_counter()
-            params, opt, metrics = compiled(params, opt, batch)
-            loss = float(metrics["loss"])  # blocks on the device computation
-            step_times.append(time.perf_counter() - t_step)
-            losses.append(loss)
-            if step % args.log_every == 0 or step == args.steps - 1:
-                print(f"step {step:5d} loss {loss:.4f} "
-                      f"gnorm {float(metrics.get('grad_norm', 0)):.3f} "
-                      f"({(time.time()-t0):.1f}s)")
-            if ckpt and (step + 1) % args.checkpoint_every == 0:
-                ckpt.save(step + 1, {"params": params, "opt": opt},
-                          metadata={"data_step": pipe.step, "arch": arch.name})
+            # one step of TensorBoard's step view when the run is profiled
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if step > start_step:
+                    batch = next_batch(step)
+                t_step = time.perf_counter()
+                params, opt, metrics = compiled(params, opt, batch)
+                loss = float(metrics["loss"])  # blocks on the device computation
+                step_times.append(time.perf_counter() - t_step)
+                losses.append(loss)
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    print(f"step {step:5d} loss {loss:.4f} "
+                          f"gnorm {float(metrics.get('grad_norm', 0)):.3f} "
+                          f"({(time.time()-t0):.1f}s)")
+                if ckpt and (step + 1) % args.checkpoint_every == 0:
+                    ckpt.save(step + 1, {"params": params, "opt": opt},
+                              metadata={"data_step": pipe.step, "arch": arch.name})
     memory_in_use = _memory_in_use(devices)  # while params and opt are live
     if ckpt:
         ckpt.wait()

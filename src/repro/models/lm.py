@@ -7,7 +7,11 @@ Design:
   * remat policy (none/selective/full — the paper's recompute-granularity)
     wraps the scan body;
   * decode uses per-layer caches threaded through the same scan as xs/ys;
-    sliding-window archs (hymba) keep a ring-buffer KV of window size.
+    sliding-window archs (hymba) keep a ring-buffer KV of window size;
+  * each layer kind's work, its pre-norm included, runs under a
+    ``jax.named_scope`` (embed, attn, ffn, moe, ssd, head), which the
+    compiled program keeps in its ops' metadata, so a profile can attribute
+    device time to it in train, prefill and decode alike.
 """
 from __future__ import annotations
 
@@ -348,60 +352,69 @@ def _layer_fn(arch: ModelArch, cfg: ModelCfg, lp: dict, h, positions, cache,
     family = arch.family
 
     if family in ("dense", "moe", "vlm", "encdec"):
-        a, kv = _attn_sublayer(
-            lp["attn"], L.norm(h, lp["ln1"], impl=cfg.norm_impl),
-            positions, arch, cfg,
-            None if cache is None else (cache["k"], cache["v"], cache["len"],
-                                        cache.get("k_scale"), cache.get("v_scale")),
-            window,
-        )
+        with jax.named_scope("attn"):
+            a, kv = _attn_sublayer(
+                lp["attn"], L.norm(h, lp["ln1"], impl=cfg.norm_impl),
+                positions, arch, cfg,
+                None if cache is None else (cache["k"], cache["v"], cache["len"],
+                                            cache.get("k_scale"), cache.get("v_scale")),
+                window,
+            )
         h = h + a
         if kv is not None:
             new_cache.update(kv)
         if family == "encdec":
-            c = _cross_sublayer(
-                lp["cross"], L.norm(h, lp["ln_cross"], impl=cfg.norm_impl),
-                cache["enc_k"], cache["enc_v"], arch, cfg,
-            )
+            with jax.named_scope("attn"):
+                c = _cross_sublayer(
+                    lp["cross"], L.norm(h, lp["ln_cross"], impl=cfg.norm_impl),
+                    cache["enc_k"], cache["enc_v"], arch, cfg,
+                )
             h = h + c
         if family == "moe":
-            m = moe_block(lp["moe"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
-                          top_k=arch.top_k, capacity_factor=cfg.capacity_factor)
+            with jax.named_scope("moe"):
+                m = moe_block(lp["moe"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
+                              top_k=arch.top_k, capacity_factor=cfg.capacity_factor)
         else:
-            m = L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
-                         constrain=cfg.constrain if cfg.act_shard else None)
+            with jax.named_scope("ffn"):
+                m = L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
+                             constrain=cfg.constrain if cfg.act_shard else None)
         h = h + m
 
     elif family == "ssm":
-        s, sc = ssm_block(
-            lp["ssm"], L.norm(h, lp["ln1"], impl=cfg.norm_impl), arch,
-            ssm_impl=cfg.ssm_impl,
-            cache=None if cache is None else (cache["conv"], cache["state"]),
-        )
+        with jax.named_scope("ssd"):
+            s, sc = ssm_block(
+                lp["ssm"], L.norm(h, lp["ln1"], impl=cfg.norm_impl), arch,
+                ssm_impl=cfg.ssm_impl,
+                cache=None if cache is None else (cache["conv"], cache["state"]),
+            )
         h = h + s
         if sc is not None:
             new_cache["conv"], new_cache["state"] = sc
 
     elif family == "hybrid":
-        # hymba: attention heads and mamba heads run in parallel on one input
-        x_in = L.norm(h, lp["ln1"], impl=cfg.norm_impl)
-        a, kv = _attn_sublayer(
-            lp["attn"], x_in, positions, arch, cfg,
-            None if cache is None else (cache["k"], cache["v"], cache["len"],
-                                        cache.get("k_scale"), cache.get("v_scale")),
-            window,
-        )
-        s, sc = ssm_block(
-            lp["ssm"], x_in, arch, ssm_impl=cfg.ssm_impl,
-            cache=None if cache is None else (cache["conv"], cache["state"]),
-        )
+        # hymba: attention heads and mamba heads run in parallel on one input,
+        # whose norm is counted with the attention
+        with jax.named_scope("attn"):
+            x_in = L.norm(h, lp["ln1"], impl=cfg.norm_impl)
+            a, kv = _attn_sublayer(
+                lp["attn"], x_in, positions, arch, cfg,
+                None if cache is None else (cache["k"], cache["v"], cache["len"],
+                                            cache.get("k_scale"), cache.get("v_scale")),
+                window,
+            )
+        with jax.named_scope("ssd"):
+            s, sc = ssm_block(
+                lp["ssm"], x_in, arch, ssm_impl=cfg.ssm_impl,
+                cache=None if cache is None else (cache["conv"], cache["state"]),
+            )
         h = h + 0.5 * (a + s)
         if kv is not None:
             new_cache.update(kv)
         if sc is not None:
             new_cache["conv"], new_cache["state"] = sc
-        h = h + L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
-                     constrain=cfg.constrain if cfg.act_shard else None)
+        with jax.named_scope("ffn"):
+            h = h + L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
+                             constrain=cfg.constrain if cfg.act_shard else None)
 
     else:
         raise ValueError(f"unknown family {family}")
@@ -430,9 +443,10 @@ def cast_params(params, dtype):
 
 def _embed_inputs(params, arch: ModelArch, cfg: ModelCfg, batch: dict):
     tokens = batch["tokens"]
-    h = params["embed"][tokens].astype(cfg.dtype)
-    if arch.frontend_stub and "frontend" in batch:
-        h = jnp.concatenate([batch["frontend"].astype(cfg.dtype), h], axis=1)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(cfg.dtype)
+        if arch.frontend_stub and "frontend" in batch:
+            h = jnp.concatenate([batch["frontend"].astype(cfg.dtype), h], axis=1)
     positions = jnp.arange(h.shape[1])
     return h, positions
 
@@ -448,17 +462,19 @@ def _encode(params, arch: ModelArch, cfg: ModelCfg, features):
         from repro.parallel.sharding import constrain_batch_sharding
 
         carry = constrain_batch_sharding(carry)
-        x_in = L.norm(carry, lp["ln1"], impl=cfg.norm_impl)
-        qkv = x_in @ lp["attn"]["wqkv"]
-        q, k, v = jnp.split(qkv, [H * D, (H + Hkv) * D], axis=-1)
-        q = L.rope(q.reshape(B, T, H, D).transpose(0, 2, 1, 3), positions)
-        k = L.rope(k.reshape(B, T, Hkv, D).transpose(0, 2, 1, 3), positions)
-        v = v.reshape(B, T, Hkv, D).transpose(0, 2, 1, 3)
-        a = ops.flash_attention(q, k, v, causal=False, impl=cfg.attn_impl)
-        a = a.transpose(0, 2, 1, 3).reshape(B, T, H * D) @ lp["attn"]["wo"]
+        with jax.named_scope("attn"):
+            x_in = L.norm(carry, lp["ln1"], impl=cfg.norm_impl)
+            qkv = x_in @ lp["attn"]["wqkv"]
+            q, k, v = jnp.split(qkv, [H * D, (H + Hkv) * D], axis=-1)
+            q = L.rope(q.reshape(B, T, H, D).transpose(0, 2, 1, 3), positions)
+            k = L.rope(k.reshape(B, T, Hkv, D).transpose(0, 2, 1, 3), positions)
+            v = v.reshape(B, T, Hkv, D).transpose(0, 2, 1, 3)
+            a = ops.flash_attention(q, k, v, causal=False, impl=cfg.attn_impl)
+            a = a.transpose(0, 2, 1, 3).reshape(B, T, H * D) @ lp["attn"]["wo"]
         carry = carry + a
-        m = L.swiglu(lp["mlp"], L.norm(carry, lp["ln2"], impl=cfg.norm_impl),
-                     constrain=cfg.constrain if cfg.act_shard else None)
+        with jax.named_scope("ffn"):
+            m = L.swiglu(lp["mlp"], L.norm(carry, lp["ln2"], impl=cfg.norm_impl),
+                         constrain=cfg.constrain if cfg.act_shard else None)
         return carry + m, None
 
     if cfg.remat != "none":
@@ -510,20 +526,24 @@ def forward_logits(params, arch: ModelArch, cfg: ModelCfg, batch: dict):
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
     h, _ = jax.lax.scan(body, h, (params["layers"], xs_cache))
 
-    h = L.norm(h, params["final_norm"], impl=cfg.norm_impl)
-    head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
-    return h @ head.astype(h.dtype)
+    with jax.named_scope("head"):
+        h = L.norm(h, params["final_norm"], impl=cfg.norm_impl)
+        head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
+        return h @ head.astype(h.dtype)
 
 
 def _encdec_train_layer(arch, cfg, lp, h, positions, cc, window):
-    a, _ = _attn_sublayer(lp["attn"], L.norm(h, lp["ln1"], impl=cfg.norm_impl),
-                          positions, arch, cfg, None, window)
+    with jax.named_scope("attn"):
+        a, _ = _attn_sublayer(lp["attn"], L.norm(h, lp["ln1"], impl=cfg.norm_impl),
+                              positions, arch, cfg, None, window)
     h = h + a
-    c = _cross_sublayer(lp["cross"], L.norm(h, lp["ln_cross"], impl=cfg.norm_impl),
-                        cc["enc_k"], cc["enc_v"], arch, cfg)
+    with jax.named_scope("attn"):
+        c = _cross_sublayer(lp["cross"], L.norm(h, lp["ln_cross"], impl=cfg.norm_impl),
+                            cc["enc_k"], cc["enc_v"], arch, cfg)
     h = h + c
-    h = h + L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
-                     constrain=cfg.constrain if cfg.act_shard else None)
+    with jax.named_scope("ffn"):
+        h = h + L.swiglu(lp["mlp"], L.norm(h, lp["ln2"], impl=cfg.norm_impl),
+                         constrain=cfg.constrain if cfg.act_shard else None)
     return h, None
 
 
@@ -532,25 +552,27 @@ def forward_train(params, arch: ModelArch, cfg: ModelCfg, batch: dict):
     logits = forward_logits(params, arch, cfg, batch)
     tokens = batch["tokens"]
     S_txt = tokens.shape[1]
-    logits_txt = logits[:, -S_txt:, :]  # frontend positions carry no loss
-    targets = tokens[:, 1:]
-    lg = logits_txt[:, :-1, :].astype(jnp.float32)
-    logz = jax.nn.logsumexp(lg, axis=-1)
-    gold = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    mask = batch.get("loss_mask")
-    if mask is not None:
-        m = mask[:, 1:].astype(jnp.float32)
-        loss = (nll * m).sum() / jnp.maximum(m.sum(), 1.0)
-    else:
-        loss = nll.mean()
+    with jax.named_scope("head"):
+        logits_txt = logits[:, -S_txt:, :]  # frontend positions carry no loss
+        targets = tokens[:, 1:]
+        lg = logits_txt[:, :-1, :].astype(jnp.float32)
+        logz = jax.nn.logsumexp(lg, axis=-1)
+        gold = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
+        nll = logz - gold
+        mask = batch.get("loss_mask")
+        if mask is not None:
+            m = mask[:, 1:].astype(jnp.float32)
+            loss = (nll * m).sum() / jnp.maximum(m.sum(), 1.0)
+        else:
+            loss = nll.mean()
     metrics = {"ce_loss": loss}
     if arch.family == "moe" and cfg.moe_aux_weight > 0:
         h, _ = _embed_inputs(params, arch, cfg, batch)
-        aux = aux_load_balance_loss(
-            jax.tree_util.tree_map(lambda x: x[0], params["layers"]["moe"]),
-            h, top_k=arch.top_k,
-        )
+        with jax.named_scope("moe"):
+            aux = aux_load_balance_loss(
+                jax.tree_util.tree_map(lambda x: x[0], params["layers"]["moe"]),
+                h, top_k=arch.top_k,
+            )
         metrics["aux_loss"] = aux
         loss = loss + cfg.moe_aux_weight * aux
     metrics["loss"] = loss
@@ -600,9 +622,10 @@ def forward_cached(params, arch: ModelArch, cfg: ModelCfg, caches: dict,
     if cfg.cast_params_in_forward:
         params = cast_params(params, cfg.dtype)
     start_pos = jnp.asarray(start_pos, jnp.int32)
-    h = params["embed"][tokens].astype(cfg.dtype)
-    if frontend is not None:
-        h = jnp.concatenate([frontend.astype(cfg.dtype), h], axis=1)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(cfg.dtype)
+        if frontend is not None:
+            h = jnp.concatenate([frontend.astype(cfg.dtype), h], axis=1)
     positions = start_pos + jnp.arange(h.shape[1])
     window = arch.sliding_window or 0
 
@@ -614,9 +637,10 @@ def forward_cached(params, arch: ModelArch, cfg: ModelCfg, caches: dict,
         return hh, new_cache
 
     h, new_caches = jax.lax.scan(body, h, (params["layers"], caches))
-    h = L.norm(h, params["final_norm"], impl=cfg.norm_impl)
-    head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
-    logits = h @ head.astype(h.dtype)
+    with jax.named_scope("head"):
+        h = L.norm(h, params["final_norm"], impl=cfg.norm_impl)
+        head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
+        logits = h @ head.astype(h.dtype)
     out = dict(caches)
     out.update(new_caches)
     return logits, out

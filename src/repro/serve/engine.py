@@ -1,5 +1,10 @@
 """Minimal batched serving engine: prefill once, decode greedily/with
 temperature, jit-compiled step functions, cache reuse across requests.
+
+``generate`` marks its host work with profiler spans (``serve.init_caches``,
+``serve.prefill``, and per token ``serve.sample``, ``serve.host_read``,
+``serve.decode``), which record only while a profiler runs, on the clock of
+the device trace.
 """
 from __future__ import annotations
 
@@ -11,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.arch import ModelArch
 from repro.models import lm
@@ -75,39 +81,44 @@ class ServeEngine:
                 f"max_len ({self.max_len}); decode positions past the KV "
                 f"cache would clobber it silently"
             )
-        caches = lm.init_caches(
-            self.arch, self.cfg, B, self.max_len,
-            enc_features=enc_features, params=self.params,
-        )
-        logits, caches = self._prefill(
-            self.params, caches=caches, tokens=jnp.asarray(prompts),
-            frontend=frontend,
-        )
+        with TraceAnnotation("serve.init_caches"):
+            caches = lm.init_caches(
+                self.arch, self.cfg, B, self.max_len,
+                enc_features=enc_features, params=self.params,
+            )
+        with TraceAnnotation("serve.prefill"):
+            logits, caches = self._prefill(
+                self.params, caches=caches, tokens=jnp.asarray(prompts),
+                frontend=frontend,
+            )
+            last = logits[:, -1, :]
         key = jax.random.PRNGKey(seed)
         out = [np.asarray(prompts)]
-        last = logits[:, -1, :]
         pos = S + frontend_len
         warmup = 0 if B in self._warm_batches else min(1, max_new_tokens)
         step_times = []
         first_token_s = 0.0
         for i in range(max_new_tokens):
             t0 = time.perf_counter()
-            if temperature > 0:
-                key, sub = jax.random.split(key)
-                nxt = jax.random.categorical(sub, last / temperature, axis=-1)
-            else:
-                nxt = jnp.argmax(last, axis=-1)
-            nxt = nxt[:, None].astype(jnp.int32)
+            with TraceAnnotation("serve.sample"):
+                if temperature > 0:
+                    key, sub = jax.random.split(key)
+                    nxt = jax.random.categorical(sub, last / temperature, axis=-1)
+                else:
+                    nxt = jnp.argmax(last, axis=-1)
+                nxt = nxt[:, None].astype(jnp.int32)
             # np.asarray blocks on the sampled token — and with it on the
             # decode dispatched last iteration — so the measured interval is
             # a true per-token step time, not just dispatch latency
-            out.append(np.asarray(nxt))
+            with TraceAnnotation("serve.host_read"):
+                out.append(np.asarray(nxt))
             if i == 0:
                 first_token_s = time.perf_counter() - t_start
-            logits, caches = self._decode(
-                self.params, caches=caches, tokens=nxt, position=pos + i
-            )
-            last = logits[:, -1, :]
+            with TraceAnnotation("serve.decode"):
+                logits, caches = self._decode(
+                    self.params, caches=caches, tokens=nxt, position=pos + i
+                )
+                last = logits[:, -1, :]
             step_times.append(time.perf_counter() - t0)
         if max_new_tokens > 0:
             self._warm_batches.add(B)

@@ -8,6 +8,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 import sys
 
 import jax
@@ -75,10 +76,11 @@ def _kernel_case(name):
 @pytest.mark.parametrize("name", ["flash_attention", "rmsnorm", "ssd"])
 def test_kernel_compiles_natively_for_v5e(one_chip, name):
     """Each Pallas kernel, at the widths chip_smoke.py runs it, compiles
-    for a v5e into a Mosaic custom call (no interpreter, no XLA stand-in)."""
+    for a v5e into a Mosaic custom call (no interpreter, no XLA stand-in)
+    that carries the kernel's own name, the op's name in a device trace."""
     fn, specs = _kernel_case(name)
-    compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile().as_text()
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call", text)
 
 
 def test_serve_decode_step_compiles_for_v5e(one_chip):
