@@ -1,5 +1,6 @@
-"""Pallas TPU kernels (flash attention, RMSNorm, Mamba-2 SSD), their pure-jnp
-oracles (``ref``) and the differentiable wrappers the model calls (``ops``)."""
+"""Pallas TPU kernels (flash attention, RMSNorm, Mamba-2 SSD), their plain-XLA
+forms (``xla_flash``, ``ssd_xla``), their pure-jnp oracles (``ref``) and the
+differentiable wrappers the model calls (``ops``)."""
 from __future__ import annotations
 
 import jax

@@ -6,9 +6,12 @@ Each op takes ``impl``:
                 recomputation, so grads are memory-frugal by construction.
                 ``interpret=True`` runs the Pallas interpreter instead, which
                 only the CPU backend may do (tests).
-  * "xla"     — the pure-jnp reference, used inside the 512-device dry-run
-                lowering where interpret-mode callbacks cannot be
-                SPMD-partitioned (DESIGN.md §5).
+  * "xla"     — plain XLA, which SPMD-partitions (the 512-device dry-run
+                lowering) and is the train path's: flash attention as a
+                scan over KV blocks (``xla_flash``), the SSD in its chunked
+                matmul form (``ssd_xla``), RMSNorm as the ``ref`` oracle.
+The oracles the kernels and the XLA forms are tested against are in ``ref``;
+``ref.ssd_scan`` is the sequential SSD recurrence.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.ssd import ssd_scan_fwd
+from repro.kernels.ssd_xla import ssd_chunked
 
 
 # ---------------------------------------------------------------------------
@@ -145,14 +149,19 @@ def ssd(
     D: Optional[jax.Array] = None,
     *,
     impl: str = "pallas",
+    chunk: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """Mamba-2 SSD mixer. Training form (no state I/O)."""
+    """Mamba-2 SSD mixer. Training form (no state I/O).
+
+    impl: "pallas" (TPU kernel; its backward differentiates the sequential
+    ``ref.ssd_scan``), "xla" (the chunked matmul form of ``ssd_xla`` in
+    chunks of ``min(chunk, S)`` positions, differentiated by autodiff)."""
     if D is None:
         D = jnp.zeros((x.shape[2],), jnp.float32)
     if impl == "pallas":
         return _ssd_pallas(x, dt, A, Bm, C, D, interpret)
-    return ref.ssd_scan(x, dt, A, Bm, C, D)
+    return ssd_chunked(x, dt, A, Bm, C, D, chunk=chunk)[0]
 
 
 def ssd_with_state(

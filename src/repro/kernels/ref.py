@@ -1,8 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+"""Pure-jnp oracles for every Pallas kernel and XLA form (the allclose
+ground truth).
 
-These are also the XLA execution path used when ``attn_impl="xla"`` — e.g.
-inside the 512-device dry-run lowering, where interpret-mode Pallas callbacks
-cannot be SPMD-partitioned (DESIGN.md §5).
+``rmsnorm`` is also the XLA execution path of ``ops.fused_rmsnorm``.
+``ssd_scan`` is the sequential SSD recurrence: the oracle of both the Pallas
+kernel and ``ops.ssd(impl="xla")`` (the chunked form in ``ssd_xla``), and the
+path of ``ops.ssd_with_state`` (decode and prefill, which carry a state).
 """
 from __future__ import annotations
 

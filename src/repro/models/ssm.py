@@ -62,7 +62,7 @@ def ssm_block(
     A = -jnp.exp(p["A_log"])  # (H,)
 
     if cache is None:
-        y = ops.ssd(xs, dt, A, Bm, C, p["D"], impl=ssm_impl)
+        y = ops.ssd(xs, dt, A, Bm, C, p["D"], impl=ssm_impl, chunk=arch.ssm_chunk)
     else:
         y, state_out = ops.ssd_with_state(
             xs, dt, A, Bm, C, p["D"], init_state=state_in, impl="xla"

@@ -1,6 +1,8 @@
 """Per-kernel allclose sweeps against the ref.py oracles, in interpret mode
 (the CPU backend cannot compile Pallas; tests/test_tpu_compile.py compiles the
 same kernels for a described v5e)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -9,6 +11,7 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.ssd import ssd_scan_fwd
+from repro.kernels.ssd_xla import ssd_chunked
 from repro.kernels.xla_flash import banded_flash_xla, flash_xla, flash_xla_train
 
 
@@ -140,11 +143,14 @@ def _ssd_inputs(B, S, H, P, N, dtype=jnp.float32, seed=0):
     return x, dt, A, Bm, C, D
 
 
-@pytest.mark.parametrize("B,S,H,P,N,chunk", [
+_SSD_SHAPES = [
     (1, 128, 2, 32, 16, 64),
-    (2, 300, 4, 64, 32, 128),   # uneven chunks
-    (1, 64, 1, 16, 8, 256),     # chunk > seq
-])
+    (2, 300, 4, 64, 32, 128),   # S not a multiple of the chunk
+    (1, 64, 1, 16, 8, 256),     # chunk > S
+]
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", _SSD_SHAPES)
 def test_ssd_kernel_vs_oracle(B, S, H, P, N, chunk):
     x, dt, A, Bm, C, D = _ssd_inputs(B, S, H, P, N)
     y, state = ssd_scan_fwd(x, dt, A, Bm, C, D, chunk=chunk, interpret=True)
@@ -183,6 +189,57 @@ def test_ssd_grad_parity():
     g1 = jax.grad(lambda x: ops.ssd(x, dt, A, Bm, C, impl="pallas", interpret=True).sum())(x)
     g2 = jax.grad(lambda x: ops.ssd(x, dt, A, Bm, C, impl="xla").sum())(x)
     assert float(jnp.abs(g1 - g2).max()) < 1e-5
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", _SSD_SHAPES)
+def test_ssd_xla_vs_oracle(B, S, H, P, N, chunk):
+    """The chunked XLA form (the train path of ops.ssd(impl="xla")) against
+    the sequential oracle: outputs and final state."""
+    x, dt, A, Bm, C, D = _ssd_inputs(B, S, H, P, N)
+    y, state = ssd_chunked(x, dt, A, Bm, C, D, chunk=chunk)
+    ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, return_state=True)
+    assert float(jnp.abs(y - ye).max()) < 2e-3
+    assert float(jnp.abs(state - se).max()) < 2e-3
+    assert jnp.array_equal(ops.ssd(x, dt, A, Bm, C, D, impl="xla", chunk=chunk), y)
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", _SSD_SHAPES)
+def test_ssd_xla_grads_vs_oracle(B, S, H, P, N, chunk):
+    """Autodiff through the chunked form against the oracle's VJP, for all
+    six inputs, each within 1e-5 of its largest entry."""
+    args = _ssd_inputs(B, S, H, P, N)
+    w = jax.random.normal(jax.random.PRNGKey(7), (B, S, H, P))
+    _, vjp = jax.vjp(lambda *a: ref.ssd_scan(*a), *args)
+    expect = vjp(w)
+    got = jax.grad(lambda *a: jnp.sum(ops.ssd(*a, impl="xla", chunk=chunk) * w),
+                   argnums=tuple(range(6)))(*args)
+    for name, g, e in zip(("x", "dt", "A", "B", "C", "D"), got, expect):
+        assert float(jnp.abs(g - e).max() / jnp.abs(e).max()) < 1e-5, name
+
+
+def test_ssd_xla_keeps_precision_under_strong_decay():
+    """As for the kernel: a chunk whose cumulative log-decay reaches
+    hundreds, where decays over a span must be summed over the span."""
+    x, dt, _, Bm, C, D = _ssd_inputs(1, 256, 2, 16, 8)
+    dt = dt + 2.0
+    A = jnp.array([-4.0, -0.5])
+    y, state = ssd_chunked(x, dt, A, Bm, C, D)
+    ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, return_state=True)
+    assert float(jnp.abs(y - ye).max() / jnp.abs(ye).max()) < 1e-6
+    assert float(jnp.abs(state - se).max() / jnp.abs(se).max()) < 1e-6
+
+
+def test_ssd_xla_train_step_has_no_per_token_loop():
+    """The jitted training form, forward and backward, at S = 2048 compiles
+    to no while loop that runs once per position (the oracle's scan does)."""
+    S = 2048
+    args = _ssd_inputs(1, S, 2, 16, 8)
+    step = jax.jit(jax.grad(lambda *a: ops.ssd(*a, impl="xla").sum(), argnums=tuple(range(6))))
+    hlo = step.lower(*args).compile().as_text()
+    loops = [line for line in hlo.splitlines() if re.search(r"\bwhile\(", line)]
+    trips = [re.search(r'"known_trip_count":\{"n":"(\d+)"\}', line) for line in loops]
+    assert all(trips), loops
+    assert str(S) not in [t.group(1) for t in trips]
 
 
 @pytest.mark.parametrize("call", ["flash_attention", "rmsnorm", "ssd"])
