@@ -5,7 +5,8 @@ v5e, without a chip, and print ``memory_analysis()`` per device.
 
 A train cell compiles its train step exactly as ``modes/train.py`` builds
 it (the searched plan's microbatches and recompute, the FSDP mesh over the
-cell's chips, taken from the described ``v5e:2x2`` host); a serve cell
+cell's chips, taken from the described ``v5e:2x2`` host), and the plain
+reference's gradient program over the cell's chips; a serve cell
 compiles the engine's prefill and decode step at the mix's batch and
 ``max_len``. Nothing runs: this says whether the programs fit, not how fast
 they are.
@@ -35,6 +36,7 @@ from repro.train.optimizer import OptState, adamw_init  # noqa: E402
 from repro.train.train_step import TrainStepCfg, make_train_step  # noqa: E402
 
 from chipbench.modes.serve import answer_lengths  # noqa: E402
+from chipbench.reference import common, family  # noqa: E402
 from chipbench.run import cell_files  # noqa: E402
 
 
@@ -82,7 +84,26 @@ def train_cell(files, devices) -> dict:
     return {"plan": f"tp={best.tensor_parallel} dp={best.data_parallel} "
                     f"mbs={best.micro_batch_size} micro={micro} "
                     f"remat={best.recompute_granularity}",
-            "train_step": _mem(compiled)}
+            "train_step": _mem(compiled), "reference_grads": reference_grads(files, devices)}
+
+
+def reference_grads(files, devices) -> dict:
+    """The plain reference's gradient program over the mix's rows, with its
+    float32 weights split over the cell's chips as ``train_reference``
+    splits them."""
+    a, t = files["config"]["arch"], files["traffic"]
+    fam = family(a["family"])
+    shapes = common.param_shapes(fam, a)
+    sh = common.leaf_shardings(shapes, devices)
+    one = jax.sharding.SingleDeviceSharding(devices[0])
+    params = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one if sh is None else sh[k])
+              for k, s in shapes.items()}
+    rows = NamedSharding(jax.sharding.Mesh(devices, ("shard",)), P())
+    tokens = jax.ShapeDtypeStruct((t["global_batch"], t["seq"]), jnp.int32,
+                                  sharding=one if sh is None else rows)
+    with jax.default_matmul_precision("highest"):
+        grads = common.row_grads(fam, a, common.Products(), sh)
+        return _mem(grads.lower(params, tokens).compile())
 
 
 def serve_cell(files, devices) -> dict:

@@ -4,19 +4,23 @@
 product of the forward and backward passes counted once (recompute left
 out), causal attention with its masked half left out, the embedding
 lookup left out. So no implementation reads above 100 % of a peak, and a
-later kernel is held to the same work as today's XLA path. The SSD mixer
-adds nothing: its sequential form (the program's XLA path) updates its
-state elementwise and contracts only the read-out, its chunked form at the
-published chunk size computes several times more products, so no count of
-its products is a floor for both.
+later kernel is held to the same work as today's XLA path.
 
-Shapes come from a configuration file's ``arch`` object (the keys of the
-program's ``ModelArch``), so this module imports nothing of the program.
+Each layer's part comes from its family's reference module
+(``chipbench/reference/__init__.py`` gives the contract), summed over the
+layers in the family's pattern; the head and the arithmetic every family
+shares are here. Shapes come from a configuration file's ``arch`` object
+(the keys of the program's ``ModelArch``), so this module imports nothing
+of the program.
 """
 from __future__ import annotations
 
 import json
+import math
 import os
+
+from chipbench.reference import family
+from chipbench.reference.common import param_shapes
 
 PEAKS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "peaks.json")
 
@@ -32,41 +36,38 @@ def peaks(device_kind: str, path: str = PEAKS_PATH) -> dict:
     return table[device_kind]
 
 
-def _head_dim(a: dict) -> int:
-    return a.get("head_dim") or a["hidden"] // a["heads"]
+def _layers(a: dict):
+    """The family's module and the kind of each layer."""
+    fam = family(a["family"])
+    return fam, fam.pattern(a)
 
 
-def layer_matmul_params(a: dict) -> int:
-    """Weights of one layer that enter a matrix product."""
-    d = a["hidden"]
-    if a["family"] == "dense":
-        hd = _head_dim(a)
-        q, kv = a["heads"] * hd, a["kv_heads"] * hd
-        return d * (q + 2 * kv) + q * d + 3 * d * a["ffn"]
-    if a["family"] == "ssm":
-        di = a.get("ssm_expand", 2) * d
-        n, h = a["ssm_state"], a["ssm_heads"]
-        return d * (2 * di + 2 * n + h) + di * d
-    raise ValueError(f"no count for family {a['family']!r}")
+def matmul_params(a: dict) -> int:
+    """Weights of all layers that enter a matrix product."""
+    fam, pattern = _layers(a)
+    return sum(fam.matmul_params(kind, a) for kind in pattern)
 
 
 def head_params(a: dict) -> int:
     return a["hidden"] * a["vocab"]
 
 
-def mixer_flops_per_token(a: dict, seq: int) -> float:
-    """Forward FLOPs per token of one layer's sequence mixer: causal
-    attention (QK^T and PV over seq/2 keys on average); none for the SSD."""
-    if a["family"] == "dense":
-        return 2.0 * seq * a["heads"] * _head_dim(a)
-    return 0.0
+def mixer_flops_per_token(a: dict, keys) -> float:
+    """Forward FLOPs per token of every layer's sequence mixer over ``keys``
+    earlier positions."""
+    fam, pattern = _layers(a)
+    return sum(fam.mixer_flops(kind, a, keys) for kind in pattern)
+
+
+def _forward_flops_per_token(a: dict, seq: int) -> float:
+    """Every matmul, and the mixers over the causal mask's seq / 2 keys on
+    average."""
+    return 2.0 * (matmul_params(a) + head_params(a)) + mixer_flops_per_token(a, seq / 2)
 
 
 def train_flops_per_token(a: dict, seq: int) -> float:
     """Forward plus backward (3 x forward) of every matmul and mixer."""
-    layers = a["num_layers"]
-    matmul = 2.0 * (layers * layer_matmul_params(a) + head_params(a))
-    return 3.0 * (matmul + layers * mixer_flops_per_token(a, seq))
+    return 3.0 * _forward_flops_per_token(a, seq)
 
 
 def train_flops_per_step(a: dict, batch: int, seq: int) -> float:
@@ -78,22 +79,19 @@ def decode_step_work(a: dict, batch: int, position: int,
     """(FLOPs, HBM bytes) one decode step of ``batch`` sequences needs when
     each holds ``position`` earlier tokens: every weight read once (the
     embedding only for the rows looked up), the KV cache of those positions
-    read once and one new entry written."""
-    if a["family"] != "dense":
-        raise ValueError("decode work is counted for attention models only")
-    layers, d, hd = a["num_layers"], a["hidden"], _head_dim(a)
-    kv_width = 2 * a["kv_heads"] * hd
-    flops = 2.0 * batch * (layers * layer_matmul_params(a) + head_params(a))
-    flops += 4.0 * batch * layers * (position + 1) * a["heads"] * hd
-    norms = (2 * layers + 1) * d
-    weights = (layers * layer_matmul_params(a) + head_params(a) + norms
-               + batch * d) * weight_bytes
-    kv = layers * batch * kv_width * (position + 1) * kv_bytes
-    return flops, float(weights + kv)
+    read once and one new entry written, and each layer's recurrent state
+    read and written (``kv_bytes`` is the cache's element size)."""
+    fam, pattern = _layers(a)
+    flops = 2.0 * batch * (matmul_params(a) + head_params(a))
+    flops += batch * mixer_flops_per_token(a, position + 1)
+    shapes = param_shapes(fam, a)
+    leaves = sum(math.prod(s) for k, s in shapes.items() if k not in ("embed", "lm_head"))
+    weights = (leaves + head_params(a) + batch * a["hidden"]) * weight_bytes
+    kv = sum(batch * fam.kv_entries(kind, a) * (position + 1) * kv_bytes for kind in pattern)
+    state = sum(fam.decode_state_bytes(kind, a, batch, kv_bytes) for kind in pattern)
+    return flops, float(weights + kv + state)
 
 
 def prefill_flops(a: dict, batch: int, prompt: int) -> float:
     """Forward FLOPs of one batch prefill (causal, masked half left out)."""
-    layers = a["num_layers"]
-    matmul = 2.0 * (layers * layer_matmul_params(a) + head_params(a))
-    return batch * prompt * (matmul + layers * mixer_flops_per_token(a, prompt))
+    return batch * prompt * _forward_flops_per_token(a, prompt)

@@ -24,7 +24,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import gc
-import importlib
 import math
 import statistics
 import time
@@ -41,6 +40,7 @@ from chipbench import trace as tr
 from chipbench import weights as W
 from chipbench.check import judge
 from chipbench.modes.train import memory_peak
+from chipbench.reference import family
 from chipbench.reference.common import Products, decode_logits, served_gaps
 
 SPANS = ("batch", "prefill", "decode")
@@ -150,9 +150,8 @@ def _spanned(sp, name, fn):
 
 
 def reference_gaps(cell, sample: dict, products: Products, dtype) -> np.ndarray:
-    fam = importlib.import_module("chipbench.reference." + cell.arch.family)
     P = cell.traffic["prompt_len"]
-    logits = decode_logits(fam, dataclasses.asdict(cell.arch), sample["seed"],
+    logits = decode_logits(family(cell.arch.family), dataclasses.asdict(cell.arch), sample["seed"],
                            sample["seqs"], P - 1, sample["served"].shape[1],
                            products, dtype=dtype)
     return logits

@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import gc
-import importlib
 import math
 import time
 
@@ -42,6 +41,7 @@ from chipbench import count
 from chipbench import trace as tr
 from chipbench import weights as W
 from chipbench.check import judge, raw_grad_norms, train_numbers
+from chipbench.reference import family
 from chipbench.reference.common import Products, train_reference
 
 SPANS = ("input", "step", "loss_read")
@@ -177,11 +177,11 @@ def memory_peak(devices) -> int:
 
 def reference_numbers(cell, readings: dict, products: Products) -> dict:
     """The plain reference's readings over the same seed and batches."""
-    fam = importlib.import_module("chipbench.reference." + cell.arch.family)
     t = cell.traffic
     hp = {k: t[k] for k in ("base_lr", "warmup_steps", "total_steps")}
-    return train_reference(fam, dataclasses.asdict(cell.arch), readings["seed"],
-                           readings["tokens"], hp, products, device=cell.devices[0])
+    return train_reference(family(cell.arch.family), dataclasses.asdict(cell.arch),
+                           readings["seed"], readings["tokens"], hp, products,
+                           devices=cell.devices)
 
 
 def run(cell) -> dict:

@@ -1,18 +1,21 @@
 """Plain float32 pieces the family references share: matrix products at
 full precision (or in simulated fp8 for the control), RMSNorm, the chunked
-next-token loss, AdamW with its schedule, and the two drivers that the
-check runs: three training steps, and greedy-decode gaps.
+next-token loss, AdamW with its schedule, the model's layers in the order
+of its family's pattern, and the two drivers that the check runs: three
+training steps, and greedy-decode gaps.
 
 Nothing here imports the program. Weights come from ``chipbench/weights.py``
 and the seed, never from the program's state.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from chipbench import weights as W
 
@@ -113,42 +116,85 @@ def adamw_step(params, grads, mu, nu, step: int, lr: float):
     return out_p, out_m, out_v, gnorm
 
 
+# --- the model's layers --------------------------------------------------------
+
+def layer_prefix(kind: str, pattern: list) -> str:
+    """Where the stacked leaves of ``kind`` sit in the tree: ``layers/`` for a
+    pattern of one kind, ``layers/<kind>/`` for one of several."""
+    return "layers/" if len(set(pattern)) == 1 else f"layers/{kind}/"
+
+
+def param_shapes(fam, a: dict) -> dict:
+    """The whole tree's leaf shapes: the rest, and each kind's layer leaves
+    stacked over that kind's count."""
+    pattern = fam.pattern(a)
+    shapes = dict(fam.rest_shapes(a))
+    for kind in dict.fromkeys(pattern):
+        pre = layer_prefix(kind, pattern)
+        n = pattern.count(kind)
+        shapes.update({pre + k: (n, *s) for k, s in fam.layer_shapes(kind, a).items()})
+    return shapes
+
+
+def layer_runs(pattern: list) -> list:
+    """(kind, index within the kind of its first layer, count) of each run of
+    consecutive layers of one kind, in order."""
+    runs, seen = [], {}
+    for kind in pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def run_layers(fam, a: dict, params: dict, h, positions, P):
+    """``h`` through every layer in the pattern's order: each run of one kind
+    is a scan over its slice of that kind's stacks, each layer recomputed in
+    the backward."""
+    pattern = fam.pattern(a)
+    for kind, first, n in layer_runs(pattern):
+        pre = layer_prefix(kind, pattern)
+        leaves = {k[len(pre):]: jax.lax.slice_in_dim(v, first, first + n)
+                  for k, v in params.items() if k.startswith(pre)}
+        layer = jax.checkpoint(lambda hh, lp, kind=kind: fam.layer(kind, lp, hh, positions, a, P))
+        h, _ = jax.lax.scan(lambda hh, lp, layer=layer: (layer(hh, lp), None), h, leaves)
+    return h
+
+
 # --- the training check ------------------------------------------------------
 
-def _stack_split(flat):
-    stacked = {k: v for k, v in flat.items() if k.startswith("layers/")}
-    rest = {k: v for k, v in flat.items() if not k.startswith("layers/")}
-    return stacked, rest
+def leaf_shardings(shapes: dict, devices) -> dict | None:
+    """Each leaf split over ``devices`` along its largest axis that their
+    number divides (replicated where none does); None on one device."""
+    n = len(devices)
+    if n == 1:
+        return None
+    mesh = Mesh(np.asarray(devices), ("shard",))
+    out = {}
+    for k, s in shapes.items():
+        axes = [i for i in sorted(range(len(s)), key=lambda i: -s[i]) if s[i] % n == 0]
+        spec = [None] * len(s)
+        if axes:
+            spec[axes[0]] = "shard"
+        out[k] = NamedSharding(mesh, PartitionSpec(*spec))
+    return out
 
 
-def train_reference(fam, a: dict, seed: int, batches: list, hp: dict,
-                    P: Products, device=None) -> dict:
-    """Three (or ``len(batches)``) AdamW steps of the plain model from the
-    seed's weights. Returns the loss of each step, the per-slice norm of the
-    first raw gradient, and the per-slice norm of the parameters' change.
-
-    Gradients are taken one row at a time (float32 activations of a whole
-    batch do not fit beside the weights); the optimizer state lives on the
-    host's CPU device. Norms are taken on the device, in float32 as the
-    program's are, the seed's weights made again for the change."""
-    device = device or jax.devices()[0]
-    cpu = jax.devices("cpu")[0]
-    shapes = fam.param_shapes(a)
-    struct = {k: jax.ShapeDtypeStruct(s, jnp.float32) for k, s in shapes.items()}
-    L = a["num_layers"]
-    on_dev = W.make(struct, seed, L)
+def row_grads(fam, a: dict, P: Products, sh: dict | None = None):
+    """The jitted (params, tokens (rows, seq)) -> (mean loss, mean gradient),
+    one row at a time; with shardings ``sh`` each row's gradient and the sum
+    are split as the weights are."""
     eps = fam.norm_eps(a)
 
+    def split(tree):
+        return tree if sh is None else jax.lax.with_sharding_constraint(tree, sh)
+
     def loss_row(params, row):
-        stacked, rest = _stack_split(params)
+        rest = {k: v for k, v in params.items() if not k.startswith("layers/")}
         h = fam.embed(rest, row[None], a)
-        pos = jnp.arange(row.shape[0])
-        layer = jax.checkpoint(lambda hh, lp: fam.layer(lp, hh, pos, a, P))
-
-        def body(hh, lp):
-            return layer(hh, {k[len("layers/"):]: v for k, v in lp.items()}), None
-
-        h, _ = jax.lax.scan(body, h, stacked)
+        h = run_layers(fam, a, params, h, jnp.arange(row.shape[0]), P)
         h = rmsnorm(h, rest["final_norm"], eps)
         return nll_sum(h[0, :-1], fam.head(rest, a), row[1:], P) / (row.shape[0] - 1)
 
@@ -156,14 +202,38 @@ def train_reference(fam, a: dict, seed: int, batches: list, hp: dict,
     def grads_of(params, tokens):
         def body(acc, row):
             l, g = jax.value_and_grad(loss_row)(params, row)
-            return (acc[0] + l, jax.tree_util.tree_map(jnp.add, acc[1], g)), None
+            return (acc[0] + l, jax.tree_util.tree_map(jnp.add, acc[1], split(g))), None
 
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+        zeros = split(jax.tree_util.tree_map(jnp.zeros_like, params))
         (lsum, gsum), _ = jax.lax.scan(body, (0.0, zeros), tokens)
         n = tokens.shape[0]
-        return lsum / n, jax.tree_util.tree_map(lambda g: g / n, gsum)
+        return lsum / n, split(jax.tree_util.tree_map(lambda g: g / n, gsum))
 
-    update = jax.jit(adamw_step)
+    return grads_of
+
+
+def train_reference(fam, a: dict, seed: int, batches: list, hp: dict,
+                    P: Products, devices=None) -> dict:
+    """Three (or ``len(batches)``) AdamW steps of the plain model from the
+    seed's weights. Returns the loss of each step, the per-slice norm of the
+    first raw gradient, and the per-slice norm of the parameters' change.
+
+    Gradients are taken one row at a time (float32 activations of a whole
+    batch do not fit beside the weights); the optimizer state lives on the
+    host's CPU device. On several ``devices`` the float32 weights, the
+    gradient sum and each row's gradient are split over them
+    (``leaf_shardings``). Norms are taken on the devices, in float32 as the
+    program's are, the seed's weights made again for the change."""
+    devices = list(devices or jax.devices()[:1])
+    cpu = jax.devices("cpu")[0]
+    shapes = param_shapes(fam, a)
+    struct = {k: jax.ShapeDtypeStruct(s, jnp.float32) for k, s in shapes.items()}
+    L = a["num_layers"]
+    sh = leaf_shardings(shapes, devices)
+    on_dev = W.make(struct, seed, L, out_shardings=sh)
+    grads_of = row_grads(fam, a, P, sh)
+    # the host's state is replaced each step: its buffers are reused in place
+    update = jax.jit(adamw_step, donate_argnums=(0, 2, 3))
     host = jax.device_put(on_dev, cpu)
     with jax.default_device(cpu):
         mu = jax.tree_util.tree_map(jnp.zeros_like, host)
@@ -181,7 +251,7 @@ def train_reference(fam, a: dict, seed: int, batches: list, hp: dict,
                 host, mu, nu, _ = update(host, grads, mu, nu, jnp.float32(i),
                                          jnp.float32(lr_at(i, hp)))
             del grads
-            on_dev = jax.device_put(host, device)
+            on_dev = jax.device_put(host, devices[0] if sh is None else sh)
     change = W.change_norms(on_dev, seed, L)
     return {"losses": losses, "grad_norms": grad_norms, "change_norms": change}
 
@@ -190,13 +260,14 @@ def train_reference(fam, a: dict, seed: int, batches: list, hp: dict,
 
 def decode_logits(fam, a: dict, seed: int, seqs: np.ndarray, first: int,
                   n_pos: int, P: Products, dtype=jnp.bfloat16) -> jax.Array:
-    """Full-sequence forward over ``seqs`` (K, T), one layer at a time with
-    each layer's weights made again from the seed (rounded to the served
-    ``dtype``, computed in float32). Returns the logits (K, n_pos, V) at
-    positions first .. first + n_pos - 1."""
+    """Full-sequence forward over ``seqs`` (K, T), one layer at a time in the
+    pattern's order, with each layer's weights made again from the seed
+    (rounded to the served ``dtype``, computed in float32). Returns the
+    logits (K, n_pos, V) at positions first .. first + n_pos - 1."""
     key = W.seed_key(seed)
     L = a["num_layers"]
-    shapes = fam.param_shapes(a)
+    pattern = fam.pattern(a)
+    shapes = param_shapes(fam, a)
     served = lambda x: x.astype(dtype).astype(jnp.float32)  # noqa: E731
     T = seqs.shape[1]
     pos = jnp.arange(T)
@@ -206,11 +277,12 @@ def decode_logits(fam, a: dict, seed: int, seqs: np.ndarray, first: int,
         rest = {"embed": served(W.leaf(key, "embed", shapes["embed"], L))}
         return fam.embed(rest, tokens, a)
 
-    @jax.jit
-    def one_layer(key, i, h):
-        lp = {k[len("layers/"):]: served(W.leaf(key, k, s[1:], L, i))
-              for k, s in shapes.items() if k.startswith("layers/")}
-        return fam.layer(lp, h, pos, a, P)
+    @functools.partial(jax.jit, static_argnums=0)
+    def one_layer(kind, key, i, h):
+        pre = layer_prefix(kind, pattern)
+        lp = {k[len(pre):]: served(W.leaf(key, k, s[1:], L, i))
+              for k, s in shapes.items() if k.startswith(pre)}
+        return fam.layer(kind, lp, h, pos, a, P)
 
     @jax.jit
     def finish(key, h):
@@ -221,8 +293,9 @@ def decode_logits(fam, a: dict, seed: int, seqs: np.ndarray, first: int,
 
     with jax.default_matmul_precision("highest"):
         h = start(key, jnp.asarray(seqs))
-        for i in range(L):
-            h = one_layer(key, i, h)
+        for kind, first_i, n in layer_runs(pattern):
+            for i in range(first_i, first_i + n):
+                h = one_layer(kind, key, i, h)
         return finish(key, h)
 
 

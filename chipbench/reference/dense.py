@@ -23,23 +23,55 @@ def norm_eps(a: dict) -> float:
     return NORM_EPS
 
 
-def param_shapes(a: dict) -> dict:
-    d, V, L, F = a["hidden"], a["vocab"], a["num_layers"], a["ffn"]
-    hd = a.get("head_dim") or d // a["heads"]
+KIND = "attn"
+
+
+def pattern(a: dict) -> list:
+    return [KIND] * a["num_layers"]
+
+
+def _head_dim(a: dict) -> int:
+    return a.get("head_dim") or a["hidden"] // a["heads"]
+
+
+def layer_shapes(kind: str, a: dict) -> dict:
+    d, F, hd = a["hidden"], a["ffn"], _head_dim(a)
     H, Hkv = a["heads"], a["kv_heads"]
-    shapes = {
-        "embed": (V, d),
-        "final_norm": (d,),
-        "layers/attn/wqkv": (L, d, (H + 2 * Hkv) * hd),
-        "layers/attn/wo": (L, H * hd, d),
-        "layers/ln1": (L, d),
-        "layers/ln2": (L, d),
-        "layers/mlp/wi": (L, d, 2 * F),
-        "layers/mlp/wo": (L, F, d),
+    return {
+        "attn/wqkv": (d, (H + 2 * Hkv) * hd),
+        "attn/wo": (H * hd, d),
+        "ln1": (d,),
+        "ln2": (d,),
+        "mlp/wi": (d, 2 * F),
+        "mlp/wo": (F, d),
     }
+
+
+def rest_shapes(a: dict) -> dict:
+    d, V = a["hidden"], a["vocab"]
+    shapes = {"embed": (V, d), "final_norm": (d,)}
     if not a.get("tie_embeddings"):
         shapes["lm_head"] = (d, V)
     return shapes
+
+
+def matmul_params(kind: str, a: dict) -> int:
+    d, hd = a["hidden"], _head_dim(a)
+    q, kv = a["heads"] * hd, a["kv_heads"] * hd
+    return d * (q + 2 * kv) + q * d + 3 * d * a["ffn"]
+
+
+def mixer_flops(kind: str, a: dict, keys) -> float:
+    """QK^T and PV: 2 x 2 x keys x heads x head_dim per token."""
+    return 4.0 * keys * a["heads"] * _head_dim(a)
+
+
+def kv_entries(kind: str, a: dict) -> int:
+    return 2 * a["kv_heads"] * _head_dim(a)
+
+
+def decode_state_bytes(kind: str, a: dict, batch: int, cache_bytes: int) -> int:
+    return 0
 
 
 def embed(rest: dict, tokens, a: dict):
@@ -89,12 +121,11 @@ def attention(q, k, v, positions, P):
     return out[:, :S]
 
 
-def layer(p: dict, h, positions, a: dict, P):
-    """One decoder layer; ``p`` holds this layer's leaves by path below
-    ``layers/``."""
+def layer(kind: str, p: dict, h, positions, a: dict, P):
+    """One decoder layer; ``p`` holds its leaves by ``layer_shapes`` path."""
     B, S, d = h.shape
     H, Hkv = a["heads"], a["kv_heads"]
-    hd = a.get("head_dim") or d // H
+    hd = _head_dim(a)
     x = rmsnorm(h, p["ln1"], NORM_EPS)
     qkv = P.mm(x, p["attn/wqkv"])
     q = qkv[..., :H * hd].reshape(B, S, H, hd)

@@ -30,25 +30,62 @@ def _dims(a: dict):
     return d, di, a["ssm_heads"], a["ssm_state"]
 
 
-def param_shapes(a: dict) -> dict:
+KIND = "ssd"
+CONV_K = 4
+
+
+def pattern(a: dict) -> list:
+    return [KIND] * a["num_layers"]
+
+
+def layer_shapes(kind: str, a: dict) -> dict:
     d, di, H, N = _dims(a)
-    L, V = a["num_layers"], a["vocab"]
     conv = di + 2 * N
-    shapes = {
-        "embed": (V, d),
-        "final_norm": (d,),
-        "layers/ln1": (L, d),
-        "layers/ssm/in_proj": (L, d, 2 * di + 2 * N + H),
-        "layers/ssm/conv_w": (L, 4, conv),
-        "layers/ssm/conv_b": (L, conv),
-        "layers/ssm/dt_bias": (L, H),
-        "layers/ssm/A_log": (L, H),
-        "layers/ssm/D": (L, H),
-        "layers/ssm/out_proj": (L, di, d),
+    return {
+        "ln1": (d,),
+        "ssm/in_proj": (d, 2 * di + 2 * N + H),
+        "ssm/conv_w": (CONV_K, conv),
+        "ssm/conv_b": (conv,),
+        "ssm/dt_bias": (H,),
+        "ssm/A_log": (H,),
+        "ssm/D": (H,),
+        "ssm/out_proj": (di, d),
     }
+
+
+def rest_shapes(a: dict) -> dict:
+    d, V = a["hidden"], a["vocab"]
+    shapes = {"embed": (V, d), "final_norm": (d,)}
     if not a.get("tie_embeddings"):
         shapes["lm_head"] = (d, V)
     return shapes
+
+
+def matmul_params(kind: str, a: dict) -> int:
+    d, di, H, N = _dims(a)
+    return d * (2 * di + 2 * N + H) + di * d
+
+
+def mixer_flops(kind: str, a: dict, keys) -> float:
+    """None of the SSD's products is a floor for every form of it: the
+    sequential form updates its state elementwise and contracts only the
+    read-out, the chunked form at the published chunk size computes
+    several times more products."""
+    return 0.0
+
+
+def kv_entries(kind: str, a: dict) -> int:
+    return 0
+
+
+def decode_state_bytes(kind: str, a: dict, batch: int, cache_bytes: int) -> int:
+    """The (batch, heads, head_dim, state) float32 SSD state and the last
+    CONV_K - 1 convolution inputs in the cache's dtype, each read and
+    written once."""
+    d, di, H, N = _dims(a)
+    ssd_state = batch * H * (di // H) * N * 4
+    conv = batch * (CONV_K - 1) * (di + 2 * N) * cache_bytes
+    return 2 * (ssd_state + conv)
 
 
 def embed(rest: dict, tokens, a: dict):
@@ -104,9 +141,8 @@ def ssd(X, dA, Bm, C, chunk: int, P):
     return (y_diag + y_off).reshape(b, l, h, p)
 
 
-def layer(p: dict, h, positions, a: dict, P):
-    """One Mamba-2 layer; ``p`` holds this layer's leaves by path below
-    ``layers/``."""
+def layer(kind: str, p: dict, h, positions, a: dict, P):
+    """One Mamba-2 layer; ``p`` holds its leaves by ``layer_shapes`` path."""
     B, S, _ = h.shape
     d, di, H, N = _dims(a)
     x = rmsnorm(h, p["ln1"], NORM_EPS)

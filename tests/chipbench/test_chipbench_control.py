@@ -14,12 +14,19 @@ SEEDS = [2**31 + 21, 2**31 + 22]
 
 
 def _cells():
+    """Each cell; one on more chips than this process has CPU devices is
+    marked to run where it has them (``conftest.py``)."""
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-        return [w["name"] for w in json.load(f)["workloads"]]
+        return [pytest.param(w["name"], marks=pytest.mark.cpu_devices(w["chips"]))
+                if w["chips"] > 1 else w["name"] for w in json.load(f)["workloads"]]
 
 
 @pytest.fixture(scope="module", params=_cells())
 def readings(request, run_dirs):
+    import jax
+
+    if len(jax.devices()) < run.cell_files(request.param)["workload"]["chips"]:
+        return None  # read in the child pytest that has the devices
     cell, files = run.make_cell(request.param, SEEDS[0], 0.0, False, rehearse=True)
     fn = control.train_readings if files["traffic"]["mode"] == "train" \
         else control.serve_readings

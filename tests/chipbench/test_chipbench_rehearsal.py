@@ -1,7 +1,8 @@
 """CPU rehearsal of every cell: each mode's window driver end to end at the
 program's reduced sizes through the same configuration, traffic and limits
 files, and the result line's schema; the check coming out false when the
-timed path is broken underneath; and the runner's refusal to run off a TPU."""
+timed path is broken underneath (a four-chip cell's also without the
+exchange between its chips); and the runner's refusal to run off a TPU."""
 import json
 import os
 import subprocess
@@ -31,6 +32,14 @@ def _cells(mode=None):
     return out
 
 
+def _params(mode=None):
+    """The cells as parameters: one on more chips than this process has CPU
+    devices is marked to run where it has them (``conftest.py``)."""
+    chips = {w["name"]: w["chips"] for w in _bench()["workloads"]}
+    return [pytest.param(w, marks=pytest.mark.cpu_devices(chips[w])) if chips[w] > 1 else w
+            for w in _cells(mode)]
+
+
 def _rehearse(workload, trace=False, seed=SEED):
     return run.execute(workload, seed, 0.3, trace, rehearse=True)
 
@@ -58,7 +67,7 @@ def _assert_schema(res, workload, trace):
         assert all(v["value"] > 0 for v in res["metrics"].values())
 
 
-@pytest.mark.parametrize("workload", _cells())
+@pytest.mark.parametrize("workload", _params())
 @pytest.mark.parametrize("trace", [False, True])
 def test_cell_rehearsal(run_dirs, workload, trace):
     """``correct`` is the check's verdict: every number within its limit.
@@ -94,7 +103,7 @@ def _half_batch(make_train_step):
 
 
 @pytest.mark.parametrize("fault", [_unchanged, _half_batch], ids=["unchanged", "half_batch"])
-@pytest.mark.parametrize("workload", _cells("train"))
+@pytest.mark.parametrize("workload", _params("train"))
 def test_broken_train_step_is_not_correct(run_dirs, monkeypatch, workload, fault):
     from chipbench.modes import train
 
@@ -103,7 +112,32 @@ def test_broken_train_step_is_not_correct(run_dirs, monkeypatch, workload, fault
     assert res["correct"] is False, res["check"]
 
 
-@pytest.mark.parametrize("workload", _cells("serve"))
+def _own_rows_only(make_train_step, chips):
+    """Without the exchange between chips, each chip's gradient is the mean
+    over its own rows: planted as the step on the first chip's rows, repeated
+    in place of the others so the batch keeps its shape."""
+    def make(*a, **k):
+        step = make_train_step(*a, **k)
+
+        def broken(params, opt, batch):
+            tokens = batch["tokens"]
+            own = tokens[: tokens.shape[0] // chips]
+            return step(params, opt, {"tokens": jnp.tile(own, (chips, 1))})
+        return broken
+    return make
+
+
+@pytest.mark.parametrize("workload", [p for p in _params("train") if not isinstance(p, str)])
+def test_train_step_without_exchange_is_not_correct(run_dirs, monkeypatch, workload):
+    from chipbench.modes import train
+
+    chips = run.cell_files(workload)["workload"]["chips"]
+    monkeypatch.setattr(train, "make_train_step", _own_rows_only(train.make_train_step, chips))
+    res = _rehearse(workload)
+    assert res["correct"] is False, res["check"]
+
+
+@pytest.mark.parametrize("workload", _params("serve"))
 def test_altered_token_is_not_correct(run_dirs, monkeypatch, workload):
     """The decode step's logits are shifted by one vocabulary id, so each
     token it produces is the neighbour of the model's best."""

@@ -121,3 +121,18 @@ def test_trace_without_device_ops_is_refused():
         tr.reduce([tr.Event("/host:CPU", "python", tr.WINDOW, 0.0, 10.0)])
     with pytest.raises(ValueError, match="window"):
         tr.reduce([tr.Event("/device:TPU:0", "XLA Ops", "dot", 0.0, 1.0)])
+
+
+def test_collective_exposed_reader(events):
+    """``collective_exposed_ms.train`` is the exposed collective time per
+    run of the train step: 7.5 µs over the chips' one run; a trace with no
+    collective gives nothing to read."""
+    from chipbench import run
+
+    red = tr.reduce(events, ())
+    assert run._read_metric("collective_exposed_ms.train", {"trace": red}) == \
+        pytest.approx(7.5e-3)
+    quiet = [ev for ev in events if not ev.name.startswith("all-reduce")]
+    red = tr.reduce(quiet, ())
+    assert red["collective_s"] == 0
+    assert run._read_metric("collective_exposed_ms.train", {"trace": red}) is None
