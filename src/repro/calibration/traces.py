@@ -98,7 +98,7 @@ class StepTrace:
         d = {
             "version": wire.WIRE_VERSION,
             "kind": TRACE_KIND,
-            "arch": dataclasses.asdict(self.arch),
+            "arch": self.arch.to_dict(),
             "strategy": self.strategy.to_dict(),
             "global_batch": self.global_batch,
             "seq": self.seq,

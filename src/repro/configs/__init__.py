@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned archs + the paper's own models.
+"""Architecture registry: the 10 assigned archs, the extra presets the chip
+benchmark runs, and the paper's own models.
 
 Each assigned arch lives in its own module exposing ``ARCH`` (the exact
 published config) and ``reduced()`` (a small same-family config for CPU
@@ -23,7 +24,10 @@ ASSIGNED = (
     "pixtral-12b",
 )
 
-_MODULES = {name: name.replace("-", "_").replace(".", "_") for name in ASSIGNED}
+# presets beside the assigned ones: models the chip benchmark runs
+EXTRA = ("granite-4.0-h-micro",)
+
+_MODULES = {name: name.replace("-", "_").replace(".", "_") for name in ASSIGNED + EXTRA}
 
 
 def _module(name: str):

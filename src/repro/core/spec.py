@@ -329,7 +329,7 @@ class SearchSpec:
             workload_d["inference"] = self.workload.inference.to_dict()
         return {
             "version": 1,
-            "arch": dataclasses.asdict(self.arch),
+            "arch": self.arch.to_dict(),
             "pool": pool_d,
             "workload": workload_d,
             "objective": dataclasses.asdict(self.objective),

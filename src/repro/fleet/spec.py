@@ -194,7 +194,8 @@ class FleetSpec:
         return {
             "version": 1,
             "pools": [dataclasses.asdict(p) for p in self.pools],
-            "workloads": [dataclasses.asdict(w) for w in self.workloads],
+            "workloads": [dict(dataclasses.asdict(w), arch=w.arch.to_dict())
+                          for w in self.workloads],
             "objective": dataclasses.asdict(self.objective),
             "limits": limits_d,
         }

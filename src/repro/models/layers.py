@@ -29,8 +29,8 @@ def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Arra
     return out.astype(x.dtype)
 
 
-def norm(x: jax.Array, w: jax.Array, impl: str = "xla") -> jax.Array:
-    return ops.fused_rmsnorm(x, w, impl=impl)
+def norm(x: jax.Array, w: jax.Array, impl: str = "xla", eps: float = 1e-6) -> jax.Array:
+    return ops.fused_rmsnorm(x, w, eps=eps, impl=impl)
 
 
 def _sliding_attention(q, k, v, window: int) -> jax.Array:

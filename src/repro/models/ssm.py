@@ -1,7 +1,9 @@
 """Mamba-2 mixer block: projections + depthwise conv + SSD scan.
 
 Single-group (G=1) SSD as in the Mamba-2 370m config: per-head scalar decay
-A, shared B/C streams of width ssm_state, headdim = d_inner / nheads.
+A, shared B/C streams of width ssm_state, headdim = d_inner / nheads; with
+``arch.ssm_gated_norm``, the gated RMSNorm rmsnorm(y * silu(z)) (gain
+``ln1``) before the out projection.
 """
 from __future__ import annotations
 
@@ -70,5 +72,11 @@ def ssm_block(
         new_cache = (new_conv, state_out)
 
     y = y.reshape(B, S, d_inner)
-    y = y * jax.nn.silu(z)  # gate
+    if arch.ssm_gated_norm:
+        # Mamba-2's gated RMSNorm over the whole inner width (one group), in
+        # float32 as the published block computes it
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = ops.fused_rmsnorm(g, p["ln1"], eps=arch.rms_norm_eps, impl="xla").astype(x.dtype)
+    else:
+        y = y * jax.nn.silu(z)  # gate
     return y @ p["out_proj"], new_cache

@@ -4,7 +4,8 @@ temperature, jit-compiled step functions, cache reuse across requests.
 ``generate`` marks its host work with profiler spans (``serve.init_caches``,
 ``serve.prefill``, and per token ``serve.sample``, ``serve.host_read``,
 ``serve.decode``), which record only while a profiler runs, on the clock of
-the device trace.
+the device trace. ``serve.init_caches`` carries the bytes it allocates for
+each kind of cache (``kv_bytes``, ``ssm_state_bytes``, ``conv_bytes``).
 """
 from __future__ import annotations
 
@@ -81,7 +82,8 @@ class ServeEngine:
                 f"max_len ({self.max_len}); decode positions past the KV "
                 f"cache would clobber it silently"
             )
-        with TraceAnnotation("serve.init_caches"):
+        nbytes = lm.cache_bytes(self.arch, self.cfg, B, self.max_len)
+        with TraceAnnotation("serve.init_caches", **nbytes):
             caches = lm.init_caches(
                 self.arch, self.cfg, B, self.max_len,
                 enc_features=enc_features, params=self.params,

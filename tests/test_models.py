@@ -86,9 +86,14 @@ def test_serve_parity_prefill_decode(name):
     max_len = S + 4 + (fe.shape[1] if fe is not None else 0)
     caches = lm.init_caches(arch, cfg, B, max_len,
                             enc_features=batch.get("enc_features"), params=params)
-    lg, caches = lm.prefill(params, arch, cfg, caches, toks[:, : S - 2], frontend=fe)
     off = fe.shape[1] if fe is not None else 0
-    assert float(jnp.abs(lg - full[:, : S - 2 + off]).max()) < 1e-4
+    # every prompt position through the cached path, and the prefill's
+    # last-position logits
+    every, _ = lm.forward_cached(params, arch, cfg, caches, toks[:, : S - 2], 0, frontend=fe)
+    assert float(jnp.abs(every - full[:, : S - 2 + off]).max()) < 1e-4
+    lg, caches = lm.prefill(params, arch, cfg, caches, toks[:, : S - 2], frontend=fe)
+    assert lg.shape == (B, 1, arch.vocab)
+    assert float(jnp.abs(lg[:, 0] - full[:, S - 3 + off]).max()) < 1e-4
     pos = S - 2 + off
     lg1, caches = lm.decode_step(params, arch, cfg, caches, toks[:, S - 2 : S - 1], pos)
     assert float(jnp.abs(lg1[:, 0] - full[:, pos]).max()) < 1e-4

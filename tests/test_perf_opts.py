@@ -27,7 +27,8 @@ def setup():
 def _serve_roundtrip(arch, params, toks, cfg):
     B, S = toks.shape
     caches = lm.init_caches(arch, cfg, B, S)
-    lg_pre, caches = lm.prefill(params, arch, cfg, caches, toks[:, : S - 1])
+    # the prefill's path with the logits of every prompt position
+    lg_pre, caches = lm.forward_cached(params, arch, cfg, caches, toks[:, : S - 1], 0)
     lg_dec, _ = lm.decode_step(params, arch, cfg, caches, toks[:, S - 1 :], S - 1)
     return lg_pre, lg_dec
 
