@@ -25,7 +25,7 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.ssd import ssd_scan_fwd
-from repro.kernels.ssd_xla import ssd_chunked
+from repro.kernels.ssd_xla import ssd_chunked, ssd_segments
 
 
 # ---------------------------------------------------------------------------
@@ -165,13 +165,18 @@ def ssd(
 
 
 def ssd_with_state(
-    x, dt, A, Bm, C, D=None, *, init_state=None, impl: str = "xla"
+    x, dt, A, Bm, C, D=None, *, init_state=None, impl: str = "xla", chunk: int = 256
 ):
-    """Decode/prefill form: returns (y, final_state). XLA path supports an
-    initial state (incremental decode); the Pallas kernel currently assumes
-    zero init (prefill) — decode steps are tiny and stay on the XLA path."""
+    """Decode/prefill form: returns (y, final_state) from ``init_state``.
+    More than one position (a prefill) runs ``ssd_xla.ssd_segments``, the
+    chunked form in a scan over segments of ``chunk`` positions; one
+    position (a decode step) is one step of the recurrence,
+    ``ref.ssd_scan``. The Pallas kernel assumes a zero initial state, so
+    ``impl="pallas"`` takes it for a prefill from zero alone."""
     if D is None:
         D = jnp.zeros((x.shape[2],), jnp.float32)
     if impl == "pallas" and init_state is None:
         return ssd_scan_fwd(x, dt, A, Bm, C, D)
+    if x.shape[1] > 1:
+        return ssd_segments(x, dt, A, Bm, C, D, chunk=chunk, init_state=init_state)
     return ref.ssd_scan(x, dt, A, Bm, C, D, init_state=init_state, return_state=True)

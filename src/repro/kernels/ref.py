@@ -3,8 +3,8 @@ ground truth).
 
 ``rmsnorm`` is also the XLA execution path of ``ops.fused_rmsnorm``.
 ``ssd_scan`` is the sequential SSD recurrence: the oracle of both the Pallas
-kernel and ``ops.ssd(impl="xla")`` (the chunked form in ``ssd_xla``), and the
-path of ``ops.ssd_with_state`` (decode and prefill, which carry a state).
+kernel and of the chunked forms in ``ssd_xla`` (``ops.ssd(impl="xla")`` and
+the prefill of ``ops.ssd_with_state``), and the decode step's path there.
 """
 from __future__ import annotations
 

@@ -16,6 +16,12 @@ against. The sequence is split into chunks of ``L`` positions (Dao & Gu
 Everything runs in float32 with ``precision=HIGHEST`` products, as the
 Pallas kernel (``kernels/ssd.py``) does. Positions past ``S`` are padded
 with ``dt = 0``: no decay and no input.
+
+``ssd_segments`` is the serve prefill's form, which carries a state in and
+out: a scan over segments of ``chunk`` positions, each one ``ssd_chunked``
+call fed the state the segment before it left, so that no temporary grows
+with the prompt (at batch 16 and 64 heads each (L x L) one is 268 MB; over
+a whole 2048-token prompt at once they would be 2.1 GB each).
 """
 from __future__ import annotations
 
@@ -61,9 +67,11 @@ def ssd_chunked(
     D: Optional[jax.Array] = None,  # (H,)
     *,
     chunk: int = 256,
+    init_state: Optional[jax.Array] = None,  # (B, H, P, N)
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (y (B, S, H, P) in x.dtype, final_state (B, H, P, N) float32),
-    for a zero initial state; chunks of ``min(chunk, S)`` positions."""
+    from ``init_state`` (zero if None); chunks of ``min(chunk, S)``
+    positions."""
     Bsz, S, H, P = x.shape
     L = min(chunk, S)
     nc = -(-S // L)
@@ -95,7 +103,13 @@ def ssd_chunked(
     upto = seg[..., :, 0] + adt[..., :1]  # [t] = sum_{u<=t}
     cdecay = _causal_exp(_segsum(upto[..., -1]))  # (B, H, nc, nc)
     h_out = jnp.einsum("bhzc,bhcpn->bhzpn", cdecay, states, precision=_HIGHEST)
-    h_in = jnp.concatenate([jnp.zeros_like(h_out[:, :, :1]), h_out[:, :, :-1]], axis=2)
+    if init_state is None:
+        h0 = jnp.zeros_like(h_out[:, :, :1])
+    else:
+        # the state carried in, decayed over every chunk up to each end
+        h0 = init_state.astype(f32)[:, :, None]
+        h_out = h_out + jnp.exp(jnp.cumsum(upto[..., -1], axis=-1))[..., None, None] * h0
+    h_in = jnp.concatenate([h0, h_out[:, :, :-1]], axis=2)
     y = y + jnp.exp(upto)[..., None] * jnp.einsum(
         "bcln,bhcpn->bhclp", Cc, h_in, precision=_HIGHEST)
 
@@ -103,3 +117,37 @@ def ssd_chunked(
     if D is not None:
         y = y + D.astype(f32)[None, None, :, None] * x.astype(f32)
     return y.astype(x.dtype), h_out[:, :, -1]
+
+
+def ssd_segments(
+    x: jax.Array,  # (B, S, H, P)
+    dt: jax.Array,  # (B, S, H), positive
+    A: jax.Array,  # (H,), negative
+    Bm: jax.Array,  # (B, S, N)
+    C: jax.Array,  # (B, S, N)
+    D: Optional[jax.Array] = None,  # (H,)
+    *,
+    chunk: int = 256,
+    init_state: Optional[jax.Array] = None,  # (B, H, P, N)
+) -> tuple[jax.Array, jax.Array]:
+    """``ssd_chunked``'s result, from a ``lax.scan`` over segments of
+    ``min(chunk, S)`` positions that carries the float32 state; the last
+    segment is padded with ``dt = 0``."""
+    Bsz, S, H, P = x.shape
+    L = min(chunk, S)
+    n = -(-S // L)
+
+    def segments(v):  # (B, S, ...) -> (n, B, L, ...), padded with zeros
+        v = jnp.pad(v, [(0, 0), (0, n * L - S)] + [(0, 0)] * (v.ndim - 2))
+        return jnp.moveaxis(v.reshape((Bsz, n, L) + v.shape[2:]), 1, 0)
+
+    def body(h, seg):
+        xs, dts, Bs, Cs = seg
+        y, h = ssd_chunked(xs, dts, A, Bs, Cs, D, chunk=L, init_state=h)
+        return h, y
+
+    if init_state is None:
+        init_state = jnp.zeros((Bsz, H, P, Bm.shape[-1]), jnp.float32)
+    h, ys = jax.lax.scan(body, init_state.astype(jnp.float32),
+                         tuple(map(segments, (x, dt, Bm, C))))
+    return jnp.moveaxis(ys, 0, 1).reshape(Bsz, n * L, H, P)[:, :S], h

@@ -799,6 +799,15 @@ def cache_bytes(arch: ModelArch, cfg: ModelCfg, batch_size: int, max_len: int) -
             "ssm_state_bytes": size.get("state", 0), "conv_bytes": size.get("conv", 0)}
 
 
+def prefill_ssd_segments(arch: ModelArch, seq_len: int) -> int:
+    """Trips of the SSD's segment scan (``ops.ssd_with_state``) that a
+    prefill of ``seq_len`` positions runs, over all its layers: one per
+    ``arch.ssm_chunk`` positions in each layer that holds SSD state; none
+    for one position, which is a step of the recurrence."""
+    _, n_ssm = _cache_layers(arch)
+    return n_ssm * -(-seq_len // arch.ssm_chunk) if seq_len > 1 else 0
+
+
 def init_caches(arch: ModelArch, cfg: ModelCfg, batch_size: int, max_len: int,
                 enc_features=None, params=None) -> dict:
     """Decode caches, each an (L, B, ...) stack over the layers that hold it

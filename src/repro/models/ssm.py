@@ -67,7 +67,7 @@ def ssm_block(
         y = ops.ssd(xs, dt, A, Bm, C, p["D"], impl=ssm_impl, chunk=arch.ssm_chunk)
     else:
         y, state_out = ops.ssd_with_state(
-            xs, dt, A, Bm, C, p["D"], init_state=state_in, impl="xla"
+            xs, dt, A, Bm, C, p["D"], init_state=state_in, impl="xla", chunk=arch.ssm_chunk
         )
         new_cache = (new_conv, state_out)
 

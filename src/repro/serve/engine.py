@@ -5,7 +5,9 @@ temperature, jit-compiled step functions, cache reuse across requests.
 ``serve.prefill``, and per token ``serve.sample``, ``serve.host_read``,
 ``serve.decode``), which record only while a profiler runs, on the clock of
 the device trace. ``serve.init_caches`` carries the bytes it allocates for
-each kind of cache (``kv_bytes``, ``ssm_state_bytes``, ``conv_bytes``).
+each kind of cache (``kv_bytes``, ``ssm_state_bytes``, ``conv_bytes``), and
+``serve.prefill`` the trips of the SSD's segment scan its prefill runs
+(``ssd_segments``).
 """
 from __future__ import annotations
 
@@ -88,7 +90,8 @@ class ServeEngine:
                 self.arch, self.cfg, B, self.max_len,
                 enc_features=enc_features, params=self.params,
             )
-        with TraceAnnotation("serve.prefill"):
+        segments = lm.prefill_ssd_segments(self.arch, S + frontend_len)
+        with TraceAnnotation("serve.prefill", ssd_segments=segments):
             logits, caches = self._prefill(
                 self.params, caches=caches, tokens=jnp.asarray(prompts),
                 frontend=frontend,

@@ -18,6 +18,7 @@ import pytest
 
 from repro.configs import ASSIGNED, get_arch, get_reduced
 from repro.core.arch import ModelArch
+from repro.kernels import ops, ref
 from repro.models import lm
 from repro.serve import ServeEngine
 from repro.train.optimizer import adamw_init
@@ -232,6 +233,48 @@ def test_engine_generates_and_spans_cache_bytes(tmp_path):
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
-                if ev.name == "serve.init_caches":
+                if ev.name in ("serve.init_caches", "serve.prefill"):
                     spans[ev.name].append(dict(ev.stats))
     assert spans["serve.init_caches"] == [lm.cache_bytes(arch, CFG, 2, 24)]
+    assert spans["serve.prefill"] == [{"ssd_segments": lm.prefill_ssd_segments(arch, 8)}]
+
+
+def test_prefill_ssd_segments():
+    """Trips of the prefill's segment scan: SSD layers x ceil(S / chunk),
+    none for a model without SSD layers or for a single position."""
+    granite = get_arch("granite-4.0-h-micro")
+    assert lm.prefill_ssd_segments(granite, 2048) == 36 * 8 == 288
+    assert lm.prefill_ssd_segments(granite, 2049) == 36 * 9
+    small = get_reduced("granite-4.0-h-micro")  # 4 Mamba layers, chunk 16
+    assert [lm.prefill_ssd_segments(small, s) for s in (1, 8, 16, 17, 40)] == [0, 4, 4, 8, 12]
+    assert lm.prefill_ssd_segments(get_reduced("mamba2-370m"), 300) == 2 * 2
+    assert lm.prefill_ssd_segments(get_arch("yi-6b"), 512) == 0
+
+
+def test_engine_prefill_matches_the_sequential_scan(monkeypatch):
+    """``generate`` over a prompt of several segments, the last one partial,
+    gives the greedy tokens of the same engine with its prefill's SSD forced
+    through the sequential oracle, and its prefill the same logits. The
+    embedding is scaled down so that the layers, not the embedding x12 of a
+    repeated token, set the greedy tokens (a scan whose segments each start
+    from zero changes them)."""
+    arch = get_reduced("granite-4.0-h-micro")
+    params = lm.init_params(arch, jax.random.PRNGKey(0))
+    params["embed"] = params["embed"] * 0.03
+    prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0, arch.vocab))
+
+    def run():
+        engine = ServeEngine(arch, CFG, params, max_len=48)
+        logits, _ = engine._prefill(params, caches=lm.init_caches(arch, CFG, 2, 48),
+                                    tokens=jnp.asarray(prompts))
+        return engine.generate(prompts, max_new_tokens=8).tokens, logits
+
+    tokens, logits = run()
+
+    def sequential(x, dt, A, Bm, C, D, *, chunk, init_state):
+        return ref.ssd_scan(x, dt, A, Bm, C, D, init_state=init_state, return_state=True)
+
+    monkeypatch.setattr(ops, "ssd_segments", sequential)
+    want_tokens, want_logits = run()
+    assert np.array_equal(tokens, want_tokens)
+    assert float(jnp.abs(logits - want_logits).max()) < 1e-4 * float(jnp.abs(want_logits).max())

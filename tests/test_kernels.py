@@ -1,18 +1,21 @@
 """Per-kernel allclose sweeps against the ref.py oracles, in interpret mode
 (the CPU backend cannot compile Pallas; tests/test_tpu_compile.py compiles the
 same kernels for a described v5e)."""
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import get_reduced
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.ssd import ssd_scan_fwd
-from repro.kernels.ssd_xla import ssd_chunked
+from repro.kernels.ssd_xla import ssd_chunked, ssd_segments
 from repro.kernels.xla_flash import banded_flash_xla, flash_xla, flash_xla_train
+from repro.models import lm
 
 
 def _qkv(B, Hq, Hkv, S, T, D, dtype=jnp.float32, seed=0):
@@ -203,6 +206,26 @@ def test_ssd_xla_vs_oracle(B, S, H, P, N, chunk):
     assert jnp.array_equal(ops.ssd(x, dt, A, Bm, C, D, impl="xla", chunk=chunk), y)
 
 
+@pytest.mark.parametrize("form", [ssd_chunked, ssd_segments], ids=lambda f: f.__name__)
+@pytest.mark.parametrize("B,S,H,P,N,chunk", _SSD_SHAPES + [(2, 97, 2, 16, 8, 32)])
+def test_ssd_xla_carries_state_vs_oracle(B, S, H, P, N, chunk, form):
+    """The chunked form and the prefill's scan over segments, each from a
+    non-zero state, against the oracle carrying that state: outputs and
+    final state (S = 97 is prime, so no chunk divides it). The steps are
+    shortened so that the state carried in still counts at the end. A zero
+    state is no state, bit for bit."""
+    x, dt, A, Bm, C, D = _ssd_inputs(B, S, H, P, N)
+    dt = dt / S
+    h0 = jax.random.normal(jax.random.PRNGKey(9), (B, H, P, N))
+    y, state = form(x, dt, A, Bm, C, D, chunk=chunk, init_state=h0)
+    ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, init_state=h0, return_state=True)
+    assert float(jnp.abs(y - ye).max()) < 2e-3
+    assert float(jnp.abs(state - se).max()) < 2e-3
+    zero = form(x, dt, A, Bm, C, D, chunk=chunk, init_state=jnp.zeros_like(h0))
+    none = form(x, dt, A, Bm, C, D, chunk=chunk)
+    assert all(jnp.array_equal(a, b) for a, b in zip(zero, none))
+
+
 @pytest.mark.parametrize("B,S,H,P,N,chunk", _SSD_SHAPES)
 def test_ssd_xla_grads_vs_oracle(B, S, H, P, N, chunk):
     """Autodiff through the chunked form against the oracle's VJP, for all
@@ -227,6 +250,21 @@ def test_ssd_xla_keeps_precision_under_strong_decay():
     ye, se = ref.ssd_scan(x, dt, A, Bm, C, D, return_state=True)
     assert float(jnp.abs(y - ye).max() / jnp.abs(ye).max()) < 1e-6
     assert float(jnp.abs(state - se).max() / jnp.abs(se).max()) < 1e-6
+    # the prefill's form: four segments, from the state the first half left
+    half = [v[:, :128] for v in (x, dt)] + [A] + [v[:, :128] for v in (Bm, C)] + [D]
+    _, h0 = ref.ssd_scan(*half, return_state=True)
+    rest = [v[:, 128:] for v in (x, dt)] + [A] + [v[:, 128:] for v in (Bm, C)] + [D]
+    y, state = ssd_segments(*rest, chunk=32, init_state=h0)
+    assert float(jnp.abs(y - ye[:, 128:]).max() / jnp.abs(ye[:, 128:]).max()) < 1e-6
+    assert float(jnp.abs(state - se).max() / jnp.abs(se).max()) < 1e-6
+
+
+def _trip_counts(compiled_text: str) -> list:
+    """The known trip count of every ``while`` of a compiled CPU program."""
+    loops = [line for line in compiled_text.splitlines() if re.search(r"\bwhile\(", line)]
+    trips = [re.search(r'"known_trip_count":\{"n":"(\d+)"\}', line) for line in loops]
+    assert all(trips), loops
+    return [int(t.group(1)) for t in trips]
 
 
 def test_ssd_xla_train_step_has_no_per_token_loop():
@@ -235,11 +273,26 @@ def test_ssd_xla_train_step_has_no_per_token_loop():
     S = 2048
     args = _ssd_inputs(1, S, 2, 16, 8)
     step = jax.jit(jax.grad(lambda *a: ops.ssd(*a, impl="xla").sum(), argnums=tuple(range(6))))
-    hlo = step.lower(*args).compile().as_text()
-    loops = [line for line in hlo.splitlines() if re.search(r"\bwhile\(", line)]
-    trips = [re.search(r'"known_trip_count":\{"n":"(\d+)"\}', line) for line in loops]
-    assert all(trips), loops
-    assert str(S) not in [t.group(1) for t in trips]
+    assert S not in _trip_counts(step.lower(*args).compile().as_text())
+
+
+def test_serve_prefill_has_no_per_token_loop():
+    """The serve prefill of an interleaved model (state carried in and out)
+    at a prompt of 64 chunks compiles to a scan over its 64 segments and no
+    loop that runs once per position; the decode step runs no such scan."""
+    arch = get_reduced("granite-4.0-h-micro")
+    cfg = lm.ModelCfg(dtype=jnp.float32, attn_impl="xla", ssm_impl="xla")
+    S = 64 * arch.ssm_chunk
+    params = jax.eval_shape(functools.partial(lm.init_params, arch), jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: lm.init_caches(arch, cfg, 1, S + 1))
+    prefill = jax.jit(functools.partial(lm.prefill, arch=arch, cfg=cfg)).lower(
+        params, caches=caches, tokens=jax.ShapeDtypeStruct((1, S), jnp.int32))
+    trips = _trip_counts(prefill.compile().as_text())
+    assert S not in trips and 64 in trips, trips
+    decode = jax.jit(functools.partial(lm.decode_step, arch=arch, cfg=cfg)).lower(
+        params, caches=caches, tokens=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        position=jax.ShapeDtypeStruct((), jnp.int32))
+    assert 64 not in _trip_counts(decode.compile().as_text())
 
 
 @pytest.mark.parametrize("call", ["flash_attention", "rmsnorm", "ssd"])
