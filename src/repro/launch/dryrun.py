@@ -13,10 +13,9 @@ compiles, fits, and report its roofline terms.
     PYTHONPATH=src python -m repro.launch.dryrun --all --pods both
 
 Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json and feed
-benchmarks/roofline.py and EXPERIMENTS.md.
+benchmarks/roofline_table.py.
 """
 import argparse
-import dataclasses
 import json
 import time
 import traceback
@@ -31,7 +30,7 @@ from repro.launch import roofline as rl
 from repro.launch.hlo_account import account
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.specs import decode_specs, prefill_specs, train_batch_specs
-from repro.models.lm import ModelCfg, decode_step, forward_cached, init_params, prefill
+from repro.models.lm import ModelCfg, decode_step, forward_cached, init_params
 from repro.parallel.sharding import batch_spec, cache_specs, make_plan, param_specs
 from repro.train.optimizer import adamw_init
 from repro.train.train_step import TrainStepCfg, make_train_step
@@ -69,38 +68,14 @@ def lower_cell(
     fsdp: bool = True,
     microbatch_rows: int = 1,
     donate: bool = True,
-    opts: frozenset = frozenset(),
 ) -> dict:
-    """Lower + compile one cell; return the roofline/memory report.
-
-    ``opts`` selects §Perf hillclimb optimizations: "pre_cast" (H1),
-    "dense_decode" (D1), "act_shard" (H2). Empty = paper-faithful baseline.
-    """
+    """Lower + compile one cell; return the roofline/memory report."""
     plan = make_plan(mesh, fsdp=fsdp)
-    act_shard = None
-    if "act_shard" in opts:
-        act_shard = {"batch": plan.batch_axes, "model": plan.model_axis}
-    kv_repeat = 1
-    if "kv_repeat" in opts and not arch.is_attention_free and arch.kv_heads:
-        tp = plan.axis_size(plan.model_axis)
-        if arch.kv_heads % tp != 0:
-            # smallest replication making the head dim tp-divisible
-            r = 1
-            while (arch.kv_heads * r) % tp != 0 and arch.kv_heads * r < arch.heads:
-                r += 1
-            kv_repeat = r if (arch.kv_heads * r) % tp == 0 else 1
-    cfg = ModelCfg(dtype=jnp.bfloat16, attn_impl="xla", ssm_impl="xla",
-                   remat=remat,
-                   decode_dense_attn="dense_decode" in opts,
-                   kv_cache_repeat=kv_repeat,
-                   kv_scatter_write="kv_scatter" in opts,
-                   kv_cache_quant="kv_quant" in opts,
-                   act_shard=act_shard)
+    cfg = ModelCfg(dtype=jnp.bfloat16, remat=remat)
     report: dict = {
         "arch": arch.name, "shape": shape.name,
         "mesh": "x".join(str(mesh.shape[a]) for a in mesh.axis_names),
         "axes": list(mesh.axis_names), "remat": remat, "fsdp": fsdp,
-        "opts": sorted(opts),
     }
     t0 = time.perf_counter()
 
@@ -118,10 +93,7 @@ def lower_cell(
         dp = plan.batch_size_divisor()
         rows_per_replica = max(shape.global_batch // dp, 1)
         K = max(rows_per_replica // microbatch_rows, 1)
-        step_cfg = TrainStepCfg(
-            num_microbatches=K, batch_axes=plan.batch_axes,
-            pre_cast="pre_cast" in opts,
-        )
+        step_cfg = TrainStepCfg(num_microbatches=K, batch_axes=plan.batch_axes)
         train_step = make_train_step(arch, cfg, step_cfg)
         jitted = jax.jit(
             train_step,
@@ -241,8 +213,6 @@ def main() -> None:
     ap.add_argument("--mesh", default=None, help="override, e.g. 4x4 or 2x2x4")
     ap.add_argument("--remat", default="full", choices=("none", "selective", "full"))
     ap.add_argument("--no-fsdp", action="store_true")
-    ap.add_argument("--opt", default="",
-                    help="comma list: pre_cast,dense_decode,act_shard")
     ap.add_argument("--tag", default="", help="suffix for artifact filenames")
     ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
@@ -264,7 +234,6 @@ def main() -> None:
             for mp in pods:
                 cells.append((arch, shape, mp))
 
-    opts = frozenset(x for x in args.opt.split(",") if x)
     n_fail = 0
     for arch, shape, mp in cells:
         mesh = _mesh_from_arg(args.mesh, mp)
@@ -274,8 +243,7 @@ def main() -> None:
             tag += f"__{args.tag}"
         print(f"=== {tag} ===", flush=True)
         try:
-            report = lower_cell(arch, shape, mesh, remat=args.remat,
-                                fsdp=not args.no_fsdp, opts=opts)
+            report = lower_cell(arch, shape, mesh, remat=args.remat, fsdp=not args.no_fsdp)
         except Exception:
             traceback.print_exc()
             report = {"arch": arch.name, "shape": shape.name, "mesh": mesh_tag,

@@ -21,7 +21,7 @@ Design:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -38,46 +38,13 @@ class ModelCfg:
     """Runtime (non-architectural) model options."""
 
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "pallas"  # "pallas" | "xla"
-    norm_impl: str = "xla"
-    ssm_impl: str = "pallas"
+    # "xla" | "pallas": the benchmark's modes pass both fields, so they stay;
+    # every caller runs "xla"
+    attn_impl: str = "xla"
+    ssm_impl: str = "xla"
     remat: str = "none"  # none | selective | full  (paper recompute-granularity)
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # --- §Perf hillclimb knobs (EXPERIMENTS.md) ---------------------------
-    cast_params_in_forward: bool = True  # False => caller pre-casts once/step
-    decode_dense_attn: bool = False  # S==1: dense masked einsum (GSPMD-sharded)
-    # store the KV cache with kv-heads replicated r-fold so the head dim is
-    # divisible by tp: the cache WRITE (dynamic-update-slice at a traced seq
-    # position) then stays shard-local instead of forcing GSPMD to replicate
-    # the whole cache per layer (§Perf item D2). Costs r-fold cache memory.
-    kv_cache_repeat: int = 1
-    # write the cache via scatter instead of dynamic-update-slice: GSPMD can
-    # partition a scatter along the (seq-)sharded dim by masking, where a
-    # DUS forces full rematerialization (§Perf item D3, zero memory cost).
-    kv_scatter_write: bool = False
-    # int8 KV cache with per-(token, head) scales: halves decode's dominant
-    # cache-read traffic at ~0.3% attention-output error (§Perf item D4).
-    kv_cache_quant: bool = False
-    # explicit activation shardings: {"batch": axes, "model": axis} or None
-    act_shard: Any = None
-
-    def constrain(self, x, dims: tuple):
-        """with_sharding_constraint using logical dim tags per position:
-        'b' -> batch axes, 'm' -> model axis, None -> unsharded."""
-        if self.act_shard is None:
-            return x
-        parts = []
-        for d in dims:
-            if d == "b":
-                parts.append(self.act_shard.get("batch"))
-            elif d == "m":
-                parts.append(self.act_shard.get("model"))
-            else:
-                parts.append(None)
-        from jax.sharding import PartitionSpec as P
-
-        return jax.lax.with_sharding_constraint(x, P(*parts))
 
 
 # ---------------------------------------------------------------------------
@@ -96,7 +63,7 @@ _ZEROS_LEAVES = ("conv_b", "dt_bias", "A_log")
 # its mixer runs under, and the key of its stacks in ``params["layers"]``
 KINDS = {"attention": "attn", "mamba": "ssd"}
 # the caches each kind of layer holds
-KIND_CACHES = {"attn": ("k", "v", "k_scale", "v_scale"), "ssd": ("conv", "state")}
+KIND_CACHES = {"attn": ("k", "v"), "ssd": ("conv", "state")}
 
 
 def layer_kinds(arch: ModelArch) -> list[str]:
@@ -259,59 +226,13 @@ def init_params(arch: ModelArch, key, dtype=jnp.float32) -> dict:
 # sub-layers
 # ---------------------------------------------------------------------------
 
-def _norm(x, w, arch: ModelArch, cfg: ModelCfg):
-    return L.norm(x, w, impl=cfg.norm_impl, eps=arch.rms_norm_eps)
+def _norm(x, w, arch: ModelArch):
+    return L.norm(x, w, eps=arch.rms_norm_eps)
 
 
 def _scaled(x, m: float):
     """x * m rounded once to x's dtype; m = 1 leaves x as it is."""
     return x if m == 1.0 else (x.astype(jnp.float32) * m).astype(x.dtype)
-
-
-def _kv_quantize(x):
-    """(B, Hkv, S, D) -> int8 values + per-(B, Hkv, S) bf16 scales."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-6) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale[..., 0].astype(jnp.bfloat16)
-
-
-def _kv_dequantize(q, scale, dtype):
-    return (q.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]).astype(dtype)
-
-
-def _dense_cached_attention(q, k, v, start_pos, *, ring: bool = False, sm_scale=None):
-    """Decode-path attention as ONE masked einsum (no kv-block scan).
-
-    For S<=16 the (B, H, S, T) logits tensor is small, and a dense einsum
-    lets GSPMD shard batch over "data" and the cache seq dim over "model"
-    with a plain psum-combined softmax — the scan-based flash path instead
-    forces a dynamic-slice of a sharded dim, which the SPMD partitioner can
-    only solve by replicating the cache ("involuntary full
-    rematerialization" warnings in the baseline dry-run). §Perf item D1.
-    """
-    B, H, S, D = q.shape
-    Hkv, T = k.shape[1], k.shape[2]
-    group = H // Hkv
-    qg = q.reshape(B, Hkv, group, S, D)
-    # bf16 inputs + f32 accumulation: .astype(f32) on the cache would make
-    # XLA materialize a full-precision cache copy every layer
-    logits = jnp.einsum(
-        "bhgsd,bhtd->bhgst", qg, k, preferred_element_type=jnp.float32
-    )
-    logits = logits / (D ** 0.5) if sm_scale is None else logits * sm_scale
-    qpos = start_pos + jnp.arange(S)
-    kpos = jnp.arange(T)
-    mask = kpos[None, :] <= qpos[:, None]
-    if ring:
-        mask = mask | ((start_pos + S - 1) >= T)
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum(
-        "bhgst,bhtd->bhgsd", probs.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(B, H, S, D).astype(q.dtype)
 
 
 def _cached_attention(q, k, v, start_pos, *, ring: bool = False, sm_scale=None):
@@ -340,25 +261,16 @@ def _attn_sublayer(p, h, positions, arch: ModelArch, cfg: ModelCfg, cache,
     k = k.reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
     if arch.qk_norm:
-        q = _norm(q, p["q_norm"], arch, cfg)
-        k = _norm(k, p["k_norm"], arch, cfg)
+        q = _norm(q, p["q_norm"], arch)
+        k = _norm(k, p["k_norm"], arch)
     if arch.rope:
         q = L.rope(q, positions)
         k = L.rope(k, positions)
     scale = arch.attention_multiplier
 
-    q = cfg.constrain(q, ("b", "m", None, None))
-    k = cfg.constrain(k, ("b", None, None, None))
-    v = cfg.constrain(v, ("b", None, None, None))
-
     new_kv = None
-    if cache is not None and cfg.kv_cache_repeat > 1:
-        r = cfg.kv_cache_repeat
-        k = jnp.repeat(k, r, axis=1)
-        v = jnp.repeat(v, r, axis=1)
     if cache is not None:
-        ck, cv, start, ck_s, cv_s = cache
-        quant = cfg.kv_cache_quant and ck_s is not None
+        ck, cv, start = cache
         T = ck.shape[2]
         if window and S >= T:
             # ring-cache prefill: banded attention over the fresh K/V, then
@@ -368,50 +280,15 @@ def _attn_sublayer(p, h, positions, arch: ModelArch, cfg: ModelCfg, cache,
             out = banded_flash_xla(q, k, v, window=window, sm_scale=scale)
             # ring invariant: slot j holds position p with p % T == j
             shift = (S - T) % T
-            k_tail = jnp.roll(k[:, :, -T:], shift, axis=2)
-            v_tail = jnp.roll(v[:, :, -T:], shift, axis=2)
-            if quant:
-                ck, ck_s = _kv_quantize(k_tail)
-                cv, cv_s = _kv_quantize(v_tail)
-            else:
-                ck = k_tail.astype(ck.dtype)
-                cv = v_tail.astype(cv.dtype)
+            ck = jnp.roll(k[:, :, -T:], shift, axis=2).astype(ck.dtype)
+            cv = jnp.roll(v[:, :, -T:], shift, axis=2).astype(cv.dtype)
         else:
             write_idx = start % T if window else start
-            if quant:
-                kq, ks = _kv_quantize(k)
-                vq, vs = _kv_quantize(v)
-            else:
-                kq, vq = k.astype(ck.dtype), v.astype(cv.dtype)
-            if cfg.kv_scatter_write:
-                idx = write_idx + jnp.arange(S)
-                ck = ck.at[:, :, idx, :].set(kq)
-                cv = cv.at[:, :, idx, :].set(vq)
-                if quant:
-                    ck_s = ck_s.at[:, :, idx].set(ks)
-                    cv_s = cv_s.at[:, :, idx].set(vs)
-            else:
-                ck = jax.lax.dynamic_update_slice(ck, kq, (0, 0, write_idx, 0))
-                cv = jax.lax.dynamic_update_slice(cv, vq, (0, 0, write_idx, 0))
-                if quant:
-                    ck_s = jax.lax.dynamic_update_slice(
-                        ck_s, ks, (0, 0, write_idx))
-                    cv_s = jax.lax.dynamic_update_slice(
-                        cv_s, vs, (0, 0, write_idx))
-            if quant:
-                k_read = _kv_dequantize(ck, ck_s, cfg.dtype)
-                v_read = _kv_dequantize(cv, cv_s, cfg.dtype)
-            else:
-                k_read, v_read = ck, cv
-            if cfg.decode_dense_attn and S <= 16:
-                out = _dense_cached_attention(q, k_read, v_read, start,
-                                              ring=bool(window), sm_scale=scale)
-            else:
-                out = _cached_attention(q, k_read, v_read, start,
-                                        ring=bool(window), sm_scale=scale)
+            kq, vq = k.astype(ck.dtype), v.astype(cv.dtype)
+            ck = jax.lax.dynamic_update_slice(ck, kq, (0, 0, write_idx, 0))
+            cv = jax.lax.dynamic_update_slice(cv, vq, (0, 0, write_idx, 0))
+            out = _cached_attention(q, ck, cv, start, ring=bool(window), sm_scale=scale)
         new_kv = {"k": ck, "v": cv}
-        if quant:
-            new_kv["k_scale"], new_kv["v_scale"] = ck_s, cv_s
     elif window and window < S:
         from repro.kernels.xla_flash import banded_flash_xla
 
@@ -443,10 +320,9 @@ def _layer_fn(arch: ModelArch, cfg: ModelCfg, lp: dict, h, positions, cache,
     if family in ("dense", "moe", "vlm", "encdec"):
         with jax.named_scope("attn"):
             a, kv = _attn_sublayer(
-                lp["attn"], _norm(h, lp["ln1"], arch, cfg),
+                lp["attn"], _norm(h, lp["ln1"], arch),
                 positions, arch, cfg,
-                None if cache is None else (cache["k"], cache["v"], cache["len"],
-                                            cache.get("k_scale"), cache.get("v_scale")),
+                None if cache is None else (cache["k"], cache["v"], cache["len"]),
                 window,
             )
         h = h + a
@@ -455,24 +331,23 @@ def _layer_fn(arch: ModelArch, cfg: ModelCfg, lp: dict, h, positions, cache,
         if family == "encdec":
             with jax.named_scope("attn"):
                 c = _cross_sublayer(
-                    lp["cross"], _norm(h, lp["ln_cross"], arch, cfg),
+                    lp["cross"], _norm(h, lp["ln_cross"], arch),
                     cache["enc_k"], cache["enc_v"], arch, cfg,
                 )
             h = h + c
         if family == "moe":
             with jax.named_scope("moe"):
-                m = moe_block(lp["moe"], _norm(h, lp["ln2"], arch, cfg),
+                m = moe_block(lp["moe"], _norm(h, lp["ln2"], arch),
                               top_k=arch.top_k, capacity_factor=cfg.capacity_factor)
         else:
             with jax.named_scope("ffn"):
-                m = L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch, cfg),
-                             constrain=cfg.constrain if cfg.act_shard else None)
+                m = L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch))
         h = h + m
 
     elif family == "ssm":
         with jax.named_scope("ssd"):
             s, sc = ssm_block(
-                lp["ssm"], _norm(h, lp["ln1"], arch, cfg), arch,
+                lp["ssm"], _norm(h, lp["ln1"], arch), arch,
                 ssm_impl=cfg.ssm_impl,
                 cache=None if cache is None else (cache["conv"], cache["state"]),
             )
@@ -484,11 +359,10 @@ def _layer_fn(arch: ModelArch, cfg: ModelCfg, lp: dict, h, positions, cache,
         # hymba: attention heads and mamba heads run in parallel on one input,
         # whose norm is counted with the attention
         with jax.named_scope("attn"):
-            x_in = _norm(h, lp["ln1"], arch, cfg)
+            x_in = _norm(h, lp["ln1"], arch)
             a, kv = _attn_sublayer(
                 lp["attn"], x_in, positions, arch, cfg,
-                None if cache is None else (cache["k"], cache["v"], cache["len"],
-                                            cache.get("k_scale"), cache.get("v_scale")),
+                None if cache is None else (cache["k"], cache["v"], cache["len"]),
                 window,
             )
         with jax.named_scope("ssd"):
@@ -502,8 +376,7 @@ def _layer_fn(arch: ModelArch, cfg: ModelCfg, lp: dict, h, positions, cache,
         if sc is not None:
             new_cache["conv"], new_cache["state"] = sc
         with jax.named_scope("ffn"):
-            h = h + L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch, cfg),
-                             constrain=cfg.constrain if cfg.act_shard else None)
+            h = h + L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch))
 
     else:
         raise ValueError(f"unknown family {family}")
@@ -521,9 +394,8 @@ def _kind_layer(kind: str, arch: ModelArch, cfg: ModelCfg, lp: dict, h, position
     if kind == "attn":
         with jax.named_scope("attn"):
             a, kv = _attn_sublayer(
-                lp["attn"], _norm(h, lp["ln1"], arch, cfg), positions, arch, cfg,
-                None if cache is None else (cache["k"], cache["v"], cache["len"],
-                                            cache.get("k_scale"), cache.get("v_scale")),
+                lp["attn"], _norm(h, lp["ln1"], arch), positions, arch, cfg,
+                None if cache is None else (cache["k"], cache["v"], cache["len"]),
                 0,
             )
         if kv is not None:
@@ -531,15 +403,14 @@ def _kind_layer(kind: str, arch: ModelArch, cfg: ModelCfg, lp: dict, h, position
     else:
         with jax.named_scope("ssd"):
             a, sc = ssm_block(
-                lp["ssm"], _norm(h, lp["ln1"], arch, cfg), arch, ssm_impl=cfg.ssm_impl,
+                lp["ssm"], _norm(h, lp["ln1"], arch), arch, ssm_impl=cfg.ssm_impl,
                 cache=None if cache is None else (cache["conv"], cache["state"]),
             )
         if sc is not None:
             new_cache["conv"], new_cache["state"] = sc
     h = h + _scaled(a, r)
     with jax.named_scope("ffn"):
-        m = L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch, cfg),
-                     constrain=cfg.constrain if cfg.act_shard else None)
+        m = L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch))
     return h + _scaled(m, r), new_cache
 
 
@@ -570,7 +441,7 @@ def _kind_runs_cached(params, arch: ModelArch, cfg: ModelCfg, h, positions, cach
     index = lambda x, i: jax.lax.dynamic_index_in_dim(x, i, keepdims=False)  # noqa: E731
     for kind, first, n in layer_runs(arch):
         stack = params["layers"][kind]
-        names = tuple(k for k in KIND_CACHES[kind] if k in caches)
+        names = KIND_CACHES[kind]
 
         def body(carry, i, kind=kind, stack=stack, names=names):
             hh, cc = carry
@@ -630,7 +501,7 @@ def _encode(params, arch: ModelArch, cfg: ModelCfg, features):
 
         carry = constrain_batch_sharding(carry)
         with jax.named_scope("attn"):
-            x_in = _norm(carry, lp["ln1"], arch, cfg)
+            x_in = _norm(carry, lp["ln1"], arch)
             qkv = x_in @ lp["attn"]["wqkv"]
             q, k, v = jnp.split(qkv, [H * D, (H + Hkv) * D], axis=-1)
             q = L.rope(q.reshape(B, T, H, D).transpose(0, 2, 1, 3), positions)
@@ -640,14 +511,13 @@ def _encode(params, arch: ModelArch, cfg: ModelCfg, features):
             a = a.transpose(0, 2, 1, 3).reshape(B, T, H * D) @ lp["attn"]["wo"]
         carry = carry + a
         with jax.named_scope("ffn"):
-            m = L.swiglu(lp["mlp"], _norm(carry, lp["ln2"], arch, cfg),
-                         constrain=cfg.constrain if cfg.act_shard else None)
+            m = L.swiglu(lp["mlp"], _norm(carry, lp["ln2"], arch))
         return carry + m, None
 
     if cfg.remat != "none":
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
     h, _ = jax.lax.scan(body, h, params["encoder"]["layers"])
-    return _norm(h, params["encoder"]["final_norm"], arch, cfg)
+    return _norm(h, params["encoder"]["final_norm"], arch)
 
 
 def _cross_kv(params, arch: ModelArch, enc_out):
@@ -666,7 +536,7 @@ def _cross_kv(params, arch: ModelArch, enc_out):
 
 def _head(params, arch: ModelArch, cfg: ModelCfg, h):
     with jax.named_scope("head"):
-        h = _norm(h, params["final_norm"], arch, cfg)
+        h = _norm(h, params["final_norm"], arch)
         head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
         logits = h @ head.astype(h.dtype)
         return logits if arch.logits_scaling == 1.0 else logits / arch.logits_scaling
@@ -674,8 +544,7 @@ def _head(params, arch: ModelArch, cfg: ModelCfg, h):
 
 def forward_logits(params, arch: ModelArch, cfg: ModelCfg, batch: dict):
     """Full-sequence forward. Returns (B, S_total, V) logits."""
-    if cfg.cast_params_in_forward:
-        params = cast_params(params, cfg.dtype)
+    params = cast_params(params, cfg.dtype)
     h, positions = _embed_inputs(params, arch, cfg, batch)
     window = arch.sliding_window or 0
     if arch.layer_types:
@@ -707,16 +576,15 @@ def forward_logits(params, arch: ModelArch, cfg: ModelCfg, batch: dict):
 
 def _encdec_train_layer(arch, cfg, lp, h, positions, cc, window):
     with jax.named_scope("attn"):
-        a, _ = _attn_sublayer(lp["attn"], _norm(h, lp["ln1"], arch, cfg),
+        a, _ = _attn_sublayer(lp["attn"], _norm(h, lp["ln1"], arch),
                               positions, arch, cfg, None, window)
     h = h + a
     with jax.named_scope("attn"):
-        c = _cross_sublayer(lp["cross"], _norm(h, lp["ln_cross"], arch, cfg),
+        c = _cross_sublayer(lp["cross"], _norm(h, lp["ln_cross"], arch),
                             cc["enc_k"], cc["enc_v"], arch, cfg)
     h = h + c
     with jax.named_scope("ffn"):
-        h = h + L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch, cfg),
-                         constrain=cfg.constrain if cfg.act_shard else None)
+        h = h + L.swiglu(lp["mlp"], _norm(h, lp["ln2"], arch))
     return h, None
 
 
@@ -772,13 +640,9 @@ def cache_layout(arch: ModelArch, cfg: ModelCfg, batch_size: int, max_len: int) 
     out: dict[str, jax.ShapeDtypeStruct] = {}
     if n_kv:
         kv_len = min(max_len, arch.sliding_window) if arch.sliding_window else max_len
-        kv_heads = arch.kv_heads * max(cfg.kv_cache_repeat, 1)
-        kv_dtype = jnp.int8 if cfg.kv_cache_quant else cfg.dtype
-        kv = jax.ShapeDtypeStruct((n_kv, batch_size, kv_heads, kv_len, arch.head_dim), kv_dtype)
+        kv = jax.ShapeDtypeStruct((n_kv, batch_size, arch.kv_heads, kv_len, arch.head_dim),
+                                  cfg.dtype)
         out["k"] = out["v"] = kv
-        if cfg.kv_cache_quant:
-            out["k_scale"] = out["v_scale"] = jax.ShapeDtypeStruct(
-                (n_kv, batch_size, kv_heads, kv_len), jnp.bfloat16)
     if n_ssm:
         di = arch.ssm_expand * arch.hidden
         H = arch.ssm_heads or max(di // 64, 1)
@@ -790,9 +654,9 @@ def cache_layout(arch: ModelArch, cfg: ModelCfg, batch_size: int, max_len: int) 
 
 
 def cache_bytes(arch: ModelArch, cfg: ModelCfg, batch_size: int, max_len: int) -> dict:
-    """Bytes of each kind of decode cache: ``kv_bytes`` (K and V with their
-    scales), ``ssm_state_bytes`` (SSD state) and ``conv_bytes`` (the
-    convolution's last inputs)."""
+    """Bytes of each kind of decode cache: ``kv_bytes`` (K and V),
+    ``ssm_state_bytes`` (SSD state) and ``conv_bytes`` (the convolution's
+    last inputs)."""
     size = {k: s.size * s.dtype.itemsize
             for k, s in cache_layout(arch, cfg, batch_size, max_len).items()}
     return {"kv_bytes": sum(size.get(k, 0) for k in KIND_CACHES["attn"]),
@@ -825,8 +689,7 @@ def forward_cached(params, arch: ModelArch, cfg: ModelCfg, caches: dict,
                    tokens: jax.Array, start_pos, frontend=None, last_only: bool = False):
     """Shared prefill/decode path: processes S tokens starting at start_pos.
     ``last_only`` gives the logits of the last position alone, (B, 1, V)."""
-    if cfg.cast_params_in_forward:
-        params = cast_params(params, cfg.dtype)
+    params = cast_params(params, cfg.dtype)
     start_pos = jnp.asarray(start_pos, jnp.int32)
     with jax.named_scope("embed"):
         h = _scaled(params["embed"][tokens].astype(cfg.dtype), arch.embedding_multiplier)

@@ -37,7 +37,7 @@ def ssm_block(
     x: jax.Array,  # (B, S, d)
     arch,
     *,
-    ssm_impl: str = "pallas",
+    ssm_impl: str = "xla",
     cache: Optional[tuple[jax.Array, jax.Array]] = None,  # (conv (B,K-1,C), state (B,H,P,N))
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]]]:
     B, S, d = x.shape
@@ -67,7 +67,7 @@ def ssm_block(
         y = ops.ssd(xs, dt, A, Bm, C, p["D"], impl=ssm_impl, chunk=arch.ssm_chunk)
     else:
         y, state_out = ops.ssd_with_state(
-            xs, dt, A, Bm, C, p["D"], init_state=state_in, impl="xla", chunk=arch.ssm_chunk
+            xs, dt, A, Bm, C, p["D"], init_state=state_in, chunk=arch.ssm_chunk
         )
         new_cache = (new_conv, state_out)
 

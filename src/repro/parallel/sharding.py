@@ -221,12 +221,6 @@ def cache_specs(arch: ModelArch, plan: ShardingPlan, cache_shape: dict) -> dict:
                 parts[2] = plan.model_axis
             elif _div(shape[3], plan, plan.model_axis):
                 parts[3] = plan.model_axis
-        elif name in ("k_scale", "v_scale"):
-            # (L, B, Hkv, T): mirror the k/v layout minus the head_dim axis
-            if _div(shape[2], plan, plan.model_axis):
-                parts[2] = plan.model_axis
-            elif _div(shape[3], plan, plan.model_axis):
-                parts[3] = plan.model_axis
         elif name == "state":
             # (L, B, H, P, N): ssm heads over model
             if _div(shape[2], plan, plan.model_axis):

@@ -4,14 +4,12 @@ One Astra strategy maps to one TrainStepCfg (DESIGN.md §5):
   micro_batch_size / num_microbatches -> lax.scan grad accumulation
   recompute_granularity               -> ModelCfg.remat
   use_distributed_optimizer           -> ShardingPlan.fsdp
-  sequence_parallel                   -> activation sharding constraints
   bf16 grad accumulation (beyond-paper gradient compression knob)
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -35,10 +33,6 @@ class TrainStepCfg:
     # (GB, ...) -> (K, GB/K, ...) must keep dim 1 (not the scan dim) sharded,
     # which needs an explicit constraint or GSPMD puts K on the devices.
     batch_axes: tuple = ()
-    # §Perf H1: cast fp32 master weights -> compute dtype ONCE per step
-    # (outside the microbatch scan) instead of per microbatch; grads are
-    # taken w.r.t. the compute-dtype weights and widened back to fp32.
-    pre_cast: bool = False
 
 
 def make_train_step(
@@ -53,30 +47,15 @@ def make_train_step(
     """
     lr = cosine_schedule(cfg.base_lr, cfg.warmup_steps, cfg.total_steps)
 
-    fwd_cfg = model_cfg
-    if cfg.pre_cast:
-        fwd_cfg = dataclasses.replace(model_cfg, cast_params_in_forward=False)
-
     def loss_fn(params, microbatch):
-        loss, metrics = forward_train(params, arch, fwd_cfg, microbatch)
-        return loss, metrics
+        return forward_train(params, arch, model_cfg, microbatch)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def train_step(params, opt_state: OptState, batch: dict):
         K = cfg.num_microbatches
-        if cfg.pre_cast:
-            from repro.models.lm import cast_params
-
-            fwd_params = cast_params(params, model_cfg.dtype)
-        else:
-            fwd_params = params
         if K == 1:
-            (loss, metrics), grads = grad_fn(fwd_params, batch)
-            if cfg.pre_cast:
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads
-                )
+            (loss, metrics), grads = grad_fn(params, batch)
         else:
             def split(x):
                 y = x.reshape((K, x.shape[0] // K) + x.shape[1:])
@@ -90,7 +69,7 @@ def make_train_step(
 
             def accum(carry, mb):
                 g_acc, l_acc = carry
-                (l, _), g = grad_fn(fwd_params, mb)
+                (l, _), g = grad_fn(params, mb)
                 g_acc = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(cfg.accum_dtype), g_acc, g
                 )

@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import ASSIGNED, get_arch, get_reduced
+from repro.configs import ASSIGNED, EXTRA, get_arch, get_reduced
 from repro.models import lm
+from repro.models.ssm import CONV_K
 
 CFG = lm.ModelCfg(dtype=jnp.float32, attn_impl="xla", ssm_impl="xla")
 
@@ -68,8 +69,7 @@ def test_full_config_matches_spec(name):
     assert (arch.num_layers, arch.hidden, arch.heads, arch.kv_heads, arch.vocab) == spec
 
 
-@pytest.mark.parametrize("name", ["qwen3-8b", "granite-moe-3b-a800m", "mamba2-370m",
-                                  "hymba-1.5b", "whisper-tiny", "pixtral-12b"])
+@pytest.mark.parametrize("name", ASSIGNED + EXTRA)
 def test_serve_parity_prefill_decode(name):
     """prefill + step-by-step decode == teacher forcing, per family."""
     arch = get_reduced(name)
@@ -99,6 +99,39 @@ def test_serve_parity_prefill_decode(name):
     assert float(jnp.abs(lg1[:, 0] - full[:, pos]).max()) < 1e-4
     lg2, _ = lm.decode_step(params, arch, cfg, caches, toks[:, S - 1 : S], pos + 1)
     assert float(jnp.abs(lg2[:, 0] - full[:, pos + 1]).max()) < 1e-4
+
+
+@pytest.mark.parametrize("name", ASSIGNED + EXTRA)
+def test_cache_layout_per_preset(name):
+    """Each family's decode caches: which, their shapes and dtypes, and the
+    bytes ``cache_bytes`` counts."""
+    arch = get_reduced(name)
+    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+    B, max_len = 3, 40
+    layout = lm.cache_layout(arch, cfg, B, max_len)
+    if arch.layer_types:
+        n_kv = sum(t == "attention" for t in arch.layer_types)
+        n_ssm = sum(t == "mamba" for t in arch.layer_types)
+    else:
+        n_kv = 0 if arch.family == "ssm" else arch.num_layers
+        n_ssm = arch.num_layers if arch.family in ("ssm", "hybrid") else 0
+    assert (n_kv > 0, n_ssm > 0) == {
+        "ssm": (False, True), "hybrid": (True, True), "interleaved": (True, True),
+    }.get(arch.family, (True, False))
+    want = {}
+    if n_kv:
+        T = min(max_len, arch.sliding_window) if arch.sliding_window else max_len
+        want["k"] = want["v"] = ((n_kv, B, arch.kv_heads, T, arch.head_dim), jnp.bfloat16)
+    if n_ssm:
+        di = arch.ssm_expand * arch.hidden
+        H = arch.ssm_heads or max(di // 64, 1)
+        want["conv"] = ((n_ssm, B, CONV_K - 1, di + 2 * arch.ssm_state), jnp.bfloat16)
+        want["state"] = ((n_ssm, B, H, di // H, arch.ssm_state), jnp.float32)
+    assert {k: (s.shape, s.dtype) for k, s in layout.items()} == want
+    nbytes = {k: s.size * s.dtype.itemsize for k, s in layout.items()}
+    assert lm.cache_bytes(arch, cfg, B, max_len) == {
+        "kv_bytes": nbytes.get("k", 0) + nbytes.get("v", 0),
+        "ssm_state_bytes": nbytes.get("state", 0), "conv_bytes": nbytes.get("conv", 0)}
 
 
 def test_hybrid_ring_cache_wraps_correctly():
